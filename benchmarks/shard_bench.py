@@ -40,12 +40,15 @@ def _rows_from_subprocess(quick: bool):
         # one device?) — fail here instead of recursing forever
         raise RuntimeError(
             "shard bench needs a multi-device mesh but the forced-device "
-            "child still sees <2 devices; set XLA_FLAGS/JAX_PLATFORMS for "
-            "a multi-device backend"
+            "child still sees <2 devices; set XLA_FLAGS for a "
+            "multi-device backend"
         )
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(os.environ)
     env["REPRO_SHARD_BENCH_CHILD"] = "1"
+    # the child simulates a mesh on forced host devices; on an accelerator
+    # host the parent already holds the chip, so the child stays on the CPU
+    env["JAX_PLATFORMS"] = "cpu"
     src = os.path.join(root, "src")
     env["PYTHONPATH"] = os.pathsep.join(
         p for p in (src, root, env.get("PYTHONPATH")) if p

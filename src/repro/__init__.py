@@ -6,5 +6,3 @@ Recht, Ré; 2012).
 """
 
 __version__ = "1.0.0"
-
-from repro import compat  # noqa: F401  (installs jax mesh-API shims)

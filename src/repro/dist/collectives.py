@@ -11,7 +11,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.kernels.decode import ref as decode_ref_lib
@@ -57,7 +56,7 @@ def sharded_flash_decode(q, k_cache, v_cache, length, mesh, *,
         of = flash_decode_combine(of, m, l, axis_name)
         return of.reshape(b, h, hd)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(
@@ -67,6 +66,6 @@ def sharded_flash_decode(q, k_cache, v_cache, length, mesh, *,
             P(),
         ),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(q, k_cache, v_cache, jnp.asarray(length, jnp.int32).reshape(1))

@@ -62,6 +62,16 @@ class CompiledPlan:
         return self.loss_trace_counter["traces"]
 
 
+def device_key() -> Tuple[int, str, str]:
+    """The hardware a plan was probed on: (device count, platform,
+    device_kind). Plans, their probed kernel and fold rates and their
+    mesh-probed shard placements are only valid there, so a plan cache
+    copied from another machine (a CPU run's ``.plan_cache/`` next to a
+    chip run) must miss."""
+    dev = jax.local_devices()[0]
+    return (jax.local_device_count(), dev.platform, dev.device_kind)
+
+
 def _fresh_stats() -> Dict[str, int]:
     return {
         "plan_cache_hits": 0,
@@ -86,8 +96,8 @@ class Engine:
         self._reports: Dict[Tuple, Tuple] = {}
         self.plan_store = plan_store
         self.stats = _fresh_stats()
-        # opt-in (REPRO_COMPILATION_CACHE_DIR): compiled executables
-        # survive process restarts alongside the PlanStore's plans
+        # compiled executables survive process restarts alongside the
+        # PlanStore's plans (JAX_COMPILATION_CACHE_DIR or <checkout>/.jax_cache)
         xla_cache.maybe_enable()
 
     # -- planning ---------------------------------------------------------
@@ -146,10 +156,7 @@ class Engine:
             query.epochs,
             query.memory_budget_bytes,
             tuple(sorted(query.hints.items())),
-            # plans (and their mesh-probed shard placements) are only
-            # valid for the device topology they were planned on
-            jax.local_device_count(),
-        )
+        ) + device_key()
 
     # -- compilation cache ------------------------------------------------
 

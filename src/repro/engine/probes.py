@@ -259,7 +259,6 @@ def _probe_implementations(agg, slab, state0, rows: int) -> Dict[str, float]:
         return {}
     from repro.kernels.igd_fused import ops as igd_ops
 
-    interpret = igd_ops.default_interpret()
     # the sequential schedule's exact per-row alphas, like the kernel lane
     alphas = agg.step_size(state0.step + jnp.arange(rows))
     out = {}
@@ -267,7 +266,8 @@ def _probe_implementations(agg, slab, state0, rows: int) -> Dict[str, float]:
         ("pallas_fused", igd_ops.igd_fold),
         ("pallas_minibatch", igd_ops.igd_fold_minibatch),
     ):
-        fn = functools.partial(op, loss=loss, interpret=interpret)
+        # interpret follows the backend: compiled on a TPU
+        fn = functools.partial(op, loss=loss)
         out[name] = time_call(
             fn, slab["x"], slab["y"], alphas, state0.model
         ) / rows
@@ -318,9 +318,12 @@ def _probe_sharded(
     devices = mesh_lib.shard_device_count()
     slab_rows = jax.tree.leaves(probe_slab)[0].shape[0]
     rows = min(n, SHARD_PROBE_ROWS, slab_rows)
+    # the planner enumerates only shard counts that divide the table, so
+    # a probed k must divide it too (Forest's 581,012 rows take k <= 4)
     k = next(
         (k for k in _SEG_PROBE_CANDIDATES
-         if rows % k == 0 and k > 1 and (k_cap is None or k <= k_cap)),
+         if rows % k == 0 and n % k == 0 and k > 1
+         and (k_cap is None or k <= k_cap)),
         None,
     )
     if k is None:

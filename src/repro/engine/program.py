@@ -72,7 +72,6 @@ from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core import mrs as mrs_lib, ordering as ordering_lib
@@ -389,8 +388,6 @@ def kernel_lane_fold(agg, loss: str, *, minibatch: bool = False,
     TPU — ``igd_fused.ops.default_interpret``)."""
     from repro.kernels.igd_fused import ops as igd_ops
 
-    if interpret is None:
-        interpret = igd_ops.default_interpret()
     op = igd_ops.igd_fold_minibatch if minibatch else igd_ops.igd_fold
 
     def lane(state, ex):
@@ -723,9 +720,9 @@ def build_shard_block(
             in_specs = (P(), P(), P())
         out_specs = (P(), P())
 
-    return shard_map(
+    return jax.shard_map(
         inner, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=False,
+        check_vma=False,
     )
 
 

@@ -1,14 +1,19 @@
-"""Opt-in jax persistent compilation cache (ROADMAP: "cross-process
-sharing of compiled executables").
+"""JAX's persistent compilation cache, at a path that outlives a process.
 
 The ``PlanStore`` eliminates re-*measuring* and re-*planning* across
-processes; the XLA executables themselves still recompiled per process.
-Setting ``REPRO_COMPILATION_CACHE_DIR=<dir>`` closes that gap: the
-``Engine``/``ServingEngine`` constructors point jax's persistent
-compilation cache at the directory, so a fresh process deserializes
-yesterday's executables instead of re-running XLA. Opt-in by env var
-because the cache trades disk (one file per executable) for compile
-time, a call the operator owns.
+processes; this module keeps the XLA executables as well, so a fresh
+process deserializes compiled programs instead of re-running XLA. The
+``Engine``/``ServingEngine`` constructors call :func:`maybe_enable`.
+
+Where the cache lives:
+
+* ``JAX_COMPILATION_CACHE_DIR``, when it is set. JAX reads the variable
+  itself, and this module sets no directory of its own.
+* Otherwise a fixed ``<checkout>/.jax_cache`` (gitignored). The path is
+  part of the cache key, so it must not move between runs.
+
+JAX's own switch, ``JAX_ENABLE_COMPILATION_CACHE=false``, turns the cache
+off (the test suite does, so that tests leave no cache files behind).
 
 The thresholds are zeroed: the engine's jitted epoch functions are small
 (milliseconds of XLA time each), below jax's default "worth persisting"
@@ -23,7 +28,11 @@ from typing import Dict, Optional
 
 import jax
 
-ENV_VAR = "REPRO_COMPILATION_CACHE_DIR"
+ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
+# <checkout>/.jax_cache: this file is <checkout>/src/repro/engine/xla_cache.py
+DEFAULT_DIR = os.path.normpath(os.path.join(
+    os.path.dirname(os.path.abspath(__file__)), "..", "..", "..", ".jax_cache"
+))
 
 # path the cache was enabled for (None = not enabled); enable-once per
 # process: jax's cache dir is global config, not per-engine state
@@ -31,31 +40,31 @@ _state: Dict[str, Optional[str]] = {"path": None, "error": None}
 
 
 def maybe_enable(env: Optional[dict] = None) -> bool:
-    """Enable the persistent compilation cache when ``ENV_VAR`` is set.
-    Returns True when the cache is (already) enabled. Never raises: a
-    bad directory degrades to normal in-process compilation."""
-    path = (os.environ if env is None else env).get(ENV_VAR, "").strip()
-    if not path:
-        return _state["path"] is not None
+    """Enable the persistent compilation cache unless JAX's switch turns
+    it off. Returns True when the cache is (already) enabled. Never
+    raises: a failure degrades to normal in-process compilation,
+    recorded in ``status()["error"]`` (a directory JAX cannot write
+    makes JAX itself warn and compile without the cache)."""
+    if not jax.config.jax_enable_compilation_cache:
+        return False
+    from_env = (os.environ if env is None else env).get(ENV_VAR, "").strip()
+    path = from_env or DEFAULT_DIR
     if _state["path"] == path:
         return True
     try:
-        os.makedirs(path, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", path)
+        if not from_env:
+            # JAX makes the directory when it first writes to it, so
+            # importing the engine (which builds a default Engine)
+            # touches no file
+            jax.config.update("jax_compilation_cache_dir", path)
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        try:
-            jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        except Exception:  # older jax: flag absent; default is fine
-            pass
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
         # jax memoizes its cache object on first compile: a process that
         # already jitted something (planner probes, warmups) would
         # silently keep running cache-less without this reset
-        try:
-            from jax._src import compilation_cache as _cc
+        from jax.experimental.compilation_cache import compilation_cache
 
-            _cc.reset_cache()
-        except Exception:
-            pass
+        compilation_cache.reset_cache()
         _state["path"] = path
         _state["error"] = None
         return True

@@ -4,11 +4,13 @@ Bismarck's transition is ``Dot_Product`` + scalar loss-gradient +
 ``Scale_And_Add`` per tuple, with the model hot in cache while tuples
 stream from the buffer pool. The TPU adaptation (DESIGN.md §5):
 
-* the model ``w`` lives in a VMEM scratch buffer for the whole aggregate
-  (initialized from HBM at grid step 0, written back at the last step);
-* examples stream HBM->VMEM in (TILE, D) blocks via the BlockSpec grid;
+* the model ``w`` lives in a [1, D] VMEM scratch buffer for the whole
+  aggregate (initialized from HBM at grid step 0, written back at the
+  last step);
+* examples stream HBM->VMEM in (TILE, D) blocks via the BlockSpec grid,
+  and each tile's labels and step sizes stream into SMEM beside them;
 * the strictly-sequential per-tuple dependence runs inside the kernel as a
-  ``fori_loop`` of VPU vector ops (8x128 lanes; D padded to 128);
+  ``fori_loop`` of VPU vector ops over [1, D] rows (D padded to 128);
 * a ``minibatch`` variant instead computes the whole tile's margins with
   one MXU matvec and applies the summed update — trading IGD purity for
   MXU utilization (both have exact jnp oracles in ref.py).
@@ -32,7 +34,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-TILE = 256  # examples per VMEM block
+TILE = 256  # examples per VMEM block (and per minibatch step)
 
 
 def _grad_scale(loss: str, margin, y):
@@ -55,12 +57,14 @@ def _igd_kernel(x_ref, y_ref, alpha_ref, w0_ref, wout_ref, wscr, *, loss: str,
         wscr[...] = w0_ref[...]
 
     def body(i, _):
-        xi = x_ref[i, :]  # [D]
+        xi = x_ref[pl.ds(i, 1), :]  # [1, D]
         w = wscr[...]
-        wx = jnp.sum(w * xi)
-        yi = y_ref[i]
+        # the margin stays a [1, 1] vector: sigmoid runs on the vector
+        # units, and only y and alpha are scalars (SMEM reads)
+        wx = jnp.sum(w * xi, axis=1, keepdims=True)
+        yi = y_ref[0, i]
         m = wx if loss == "lsq" else yi * wx
-        c = _grad_scale(loss, m, yi) * alpha_ref[i]
+        c = _grad_scale(loss, m, yi) * alpha_ref[0, i]
         wscr[...] = w - c * xi  # Scale_And_Add
         return 0
 
@@ -71,30 +75,6 @@ def _igd_kernel(x_ref, y_ref, alpha_ref, w0_ref, wout_ref, wscr, *, loss: str,
         wout_ref[...] = wscr[...]
 
 
-def igd_fold(x, y, alpha, w0, *, loss: str = "lr", interpret: bool = False):
-    """Sequential IGD over all n examples. x: [N, D] f32 (N % TILE == 0,
-    D % 128 == 0), y/alpha: [N], w0: [D] -> final w [D]."""
-    n, d = x.shape
-    assert n % TILE == 0, f"N={n} not a multiple of {TILE}"
-    assert d % 128 == 0, f"D={d} not a multiple of 128"
-    n_tiles = n // TILE
-    kern = functools.partial(_igd_kernel, loss=loss, n_tiles=n_tiles)
-    return pl.pallas_call(
-        kern,
-        grid=(n_tiles,),
-        in_specs=[
-            pl.BlockSpec((TILE, d), lambda t: (t, 0)),
-            pl.BlockSpec((TILE,), lambda t: (t,)),
-            pl.BlockSpec((TILE,), lambda t: (t,)),
-            pl.BlockSpec((d,), lambda t: (0,)),
-        ],
-        out_specs=pl.BlockSpec((d,), lambda t: (0,)),
-        out_shape=jax.ShapeDtypeStruct((d,), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((d,), jnp.float32)],
-        interpret=interpret,
-    )(x, y, alpha, w0)
-
-
 def _minibatch_kernel(x_ref, y_ref, alpha_ref, w0_ref, wout_ref, wscr, *,
                       loss: str, n_tiles: int):
     t = pl.program_id(0)
@@ -103,12 +83,16 @@ def _minibatch_kernel(x_ref, y_ref, alpha_ref, w0_ref, wout_ref, wscr, *,
     def _init():
         wscr[...] = w0_ref[...]
 
-    w = wscr[...]
-    wx = x_ref[...] @ w  # [TILE] — one MXU matvec for the whole tile
-    y = y_ref[...]
+    w = wscr[...]  # [1, D]
+    x = x_ref[...]  # [TILE, D]
+    # [1, D] x [TILE, D]^T -> [1, TILE]: one MXU pass for the tile's margins
+    wx = jax.lax.dot_general(
+        w, x, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    )
+    y = y_ref[...]  # [1, TILE]
     m = wx if loss == "lsq" else y * wx
     c = _grad_scale(loss, m, y) * alpha_ref[...]
-    upd = c @ x_ref[...]  # [D]
+    upd = jnp.dot(c, x, preferred_element_type=jnp.float32)  # [1, D]
     wscr[...] = w - upd / x_ref.shape[0]
 
     @pl.when(t == n_tiles - 1)
@@ -116,25 +100,57 @@ def _minibatch_kernel(x_ref, y_ref, alpha_ref, w0_ref, wout_ref, wscr, *,
         wout_ref[...] = wscr[...]
 
 
-def igd_fold_minibatch(x, y, alpha, w0, *, loss: str = "lr",
-                       interpret: bool = False):
-    """Minibatch variant: one gradient step per TILE (mean gradient),
-    margins computed with an MXU matmul."""
+def _fold_call(kernel, x, y, alpha, w0, *, loss: str, y_alpha_space,
+               interpret: bool):
+    """Shared pallas_call plumbing. x: [N, D] f32 (N % TILE == 0,
+    D % 128 == 0), y/alpha: [N], w0: [D] -> final w [D].
+
+    Layouts the TPU compiler accepts: the model is a [1, D] block (and a
+    [1, D] VMEM scratch carried across the sequential grid), and y/alpha
+    ride as [N / TILE, 1, TILE] so each grid step's block spans the
+    array's two minor dims, in ``y_alpha_space`` (SMEM for per-row
+    scalar reads, None for VMEM vectors)."""
     n, d = x.shape
-    assert n % TILE == 0 and d % 128 == 0
+    assert n % TILE == 0, f"N={n} not a multiple of {TILE}"
+    assert d % 128 == 0, f"D={d} not a multiple of 128"
     n_tiles = n // TILE
-    kern = functools.partial(_minibatch_kernel, loss=loss, n_tiles=n_tiles)
-    return pl.pallas_call(
-        kern,
+    row_spec = pl.BlockSpec(
+        (None, 1, TILE), lambda t: (t, 0, 0), memory_space=y_alpha_space
+    )
+    model_spec = pl.BlockSpec((1, d), lambda t: (0, 0))
+    out = pl.pallas_call(
+        functools.partial(kernel, loss=loss, n_tiles=n_tiles),
         grid=(n_tiles,),
         in_specs=[
             pl.BlockSpec((TILE, d), lambda t: (t, 0)),
-            pl.BlockSpec((TILE,), lambda t: (t,)),
-            pl.BlockSpec((TILE,), lambda t: (t,)),
-            pl.BlockSpec((d,), lambda t: (0,)),
+            row_spec,
+            row_spec,
+            model_spec,
         ],
-        out_specs=pl.BlockSpec((d,), lambda t: (0,)),
-        out_shape=jax.ShapeDtypeStruct((d,), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((d,), jnp.float32)],
+        out_specs=model_spec,
+        out_shape=jax.ShapeDtypeStruct((1, d), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((1, d), jnp.float32)],
         interpret=interpret,
-    )(x, y, alpha, w0)
+    )(
+        x,
+        y.reshape(n_tiles, 1, TILE),
+        alpha.reshape(n_tiles, 1, TILE),
+        w0.reshape(1, d),
+    )
+    return out[0]
+
+
+def igd_fold(x, y, alpha, w0, *, loss: str = "lr", interpret: bool = False):
+    """Sequential IGD over all n examples; y and alpha are read as
+    scalars from SMEM, one per row."""
+    return _fold_call(_igd_kernel, x, y, alpha, w0, loss=loss,
+                      y_alpha_space=pltpu.SMEM, interpret=interpret)
+
+
+def igd_fold_minibatch(x, y, alpha, w0, *, loss: str = "lr",
+                       interpret: bool = False):
+    """Minibatch variant: one gradient step per TILE (mean gradient),
+    margins computed with an MXU matmul; y and alpha are [1, TILE]
+    vectors in VMEM."""
+    return _fold_call(_minibatch_kernel, x, y, alpha, w0, loss=loss,
+                      y_alpha_space=None, interpret=interpret)

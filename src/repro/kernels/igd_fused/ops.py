@@ -4,16 +4,20 @@ These are the lane bodies behind the EpochProgram compiler's
 ``implementation`` axis (``repro.engine.program.build_program`` lowers
 serial lane bodies of kernel-eligible plans through ``igd_fold`` /
 ``igd_fold_minibatch``; the planner prices them against the XLA fold
-from micro-probes — see ``repro.engine.probes``). On CPU (no TPU) the
-kernels run in interpret mode; on real hardware they compile
-(``default_interpret`` picks per backend, which is what the engine
-passes).
+from micro-probes — see ``repro.engine.probes``). ``interpret``
+defaults to the backend (``default_interpret``): interpret mode on the
+CPU, compiled on a TPU, so no caller runs the interpreter on the chip
+by leaving the argument out.
 
 Inputs of any (N, D) are padded to the kernel's (TILE, 128) tiling by
-``_pad``; padded rows carry ``alpha = 0`` so the transition is a no-op
-for every loss (including ``lsq``, where the pad's margin is w·x with
-y = 0 — the step is ``alpha * (margin - y) * x`` and the zero alpha
-kills it; pinned by tests/test_kernels.py)."""
+``_pad``. Padded rows carry ``alpha = 0``, so their transitions are
+bitwise no-ops for every loss (including ``lsq``, where the pad's margin
+is w·x with y = 0 — the step is ``alpha * (margin - y) * x`` and the
+zero alpha kills it). Padded columns are zero, so they add nothing to
+the margin, but they change the length of the margin's reduction and
+with it the order of its additions: D padding agrees with the unpadded
+fold to fp32 tolerance, not bit for bit (pinned by
+tests/test_kernels.py)."""
 
 from __future__ import annotations
 
@@ -31,6 +35,10 @@ def default_interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
+def _resolve(interpret):
+    return default_interpret() if interpret is None else interpret
+
+
 def _pad(x, y, alpha, w0):
     n, d = x.shape
     dp = (-d) % 128
@@ -46,17 +54,18 @@ def _pad(x, y, alpha, w0):
 
 
 @functools.partial(jax.jit, static_argnames=("loss", "interpret", "use_kernel"))
-def igd_fold(x, y, alpha, w0, *, loss="lr", interpret=True, use_kernel=True):
+def igd_fold(x, y, alpha, w0, *, loss="lr", interpret=None, use_kernel=True):
     """Bismarck transition fold over (x, y) with per-step sizes alpha."""
     if not use_kernel:
         return R.igd_fold_ref(x, y, alpha, w0, loss=loss)
     xp, yp, ap, wp, d = _pad(x, y, alpha, w0)
-    out = K.igd_fold(xp, yp, ap, wp, loss=loss, interpret=interpret)
+    out = K.igd_fold(xp, yp, ap, wp, loss=loss,
+                     interpret=_resolve(interpret))
     return out[:d]
 
 
 @functools.partial(jax.jit, static_argnames=("loss", "interpret", "use_kernel"))
-def igd_fold_minibatch(x, y, alpha, w0, *, loss="lr", interpret=True,
+def igd_fold_minibatch(x, y, alpha, w0, *, loss="lr", interpret=None,
                        use_kernel=True):
     """One mean-gradient step per TILE rows (margins via one MXU matvec).
 
@@ -70,5 +79,6 @@ def igd_fold_minibatch(x, y, alpha, w0, *, loss="lr", interpret=True,
         out = R.igd_fold_minibatch_ref(xp, yp, ap, wp, loss=loss, tile=K.TILE)
         return out[:d]
     xp, yp, ap, wp, d = _pad(x, y, alpha, w0)
-    out = K.igd_fold_minibatch(xp, yp, ap, wp, loss=loss, interpret=interpret)
+    out = K.igd_fold_minibatch(xp, yp, ap, wp, loss=loss,
+                               interpret=_resolve(interpret))
     return out[:d]

@@ -4,6 +4,9 @@ import sys
 # Tests run on the single real CPU device (the dry-run sets its own device
 # count in a subprocess); keep XLA quiet and deterministic.
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
+# JAX's own switch for the persistent compilation cache: tests (and the
+# subprocesses they start) leave no cache files in the checkout
+os.environ.setdefault("JAX_ENABLE_COMPILATION_CACHE", "false")
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 

@@ -87,7 +87,7 @@ print("DRYRUN_SMALL_OK")
 def _run(script: str, marker: str):
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
-    env.pop("JAX_PLATFORMS", None)
+    env["JAX_PLATFORMS"] = "cpu"  # a forced-host-device mesh, never the chip
     out = subprocess.run(
         [sys.executable, "-c", script], env=env, capture_output=True,
         text=True, timeout=600,

@@ -74,7 +74,7 @@ print("ELASTIC_OK")
 def test_elastic_restore_across_meshes():
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
-    env.pop("JAX_PLATFORMS", None)
+    env["JAX_PLATFORMS"] = "cpu"  # a forced-host-device mesh, never the chip
     out = subprocess.run(
         [sys.executable, "-c", SCRIPT], env=env, capture_output=True,
         text=True, timeout=600,

@@ -78,18 +78,25 @@ def test_igd_fold_padding_matrix(loss, n, d):
 def test_igd_pad_rows_are_bitwise_noops(loss, n, d):
     """The regression the ragged tail relies on: _pad's rows carry
     alpha=0, so the transition w - alpha*c*x leaves w untouched EXACTLY
-    (0.0 * anything-finite = 0.0; w - 0 = w bitwise), and the D padding
-    appends zero columns whose dot contribution is an exact +0.0. For
-    lsq in particular the pad's margin is w·x with y=0 — nonzero! — and
-    only the zero alpha kills the step. Pinned bit-equal, not allclose:
-    a future pad scheme that merely approximates the no-op must fail."""
+    (0.0 * anything-finite = 0.0; w - 0 = w bitwise). For lsq in
+    particular the pad's margin is w·x with y=0 — nonzero! — and only
+    the zero alpha kills the step. Row padding is pinned bit-equal, not
+    allclose: a future pad scheme that merely approximates the no-op
+    must fail. Column padding appends zero features: they never move off
+    their zero init (exact), but the longer margin reduction adds in a
+    different order, so the real columns agree to fp32 tolerance."""
     x, y, alpha, w0 = _igd_inputs(n, d)
     xp, yp, ap, wp, d_out = igd_ops._pad(x, y, alpha, w0)
     assert d_out == d
     assert xp.shape[0] % igd_kernel.TILE == 0 and xp.shape[1] % 128 == 0
-    ref_padded = igd_ref.igd_fold_ref(xp, yp, ap, wp, loss=loss)
     ref_raw = igd_ref.igd_fold_ref(x, y, alpha, w0, loss=loss)
-    assert np.array_equal(np.asarray(ref_padded[:d]), np.asarray(ref_raw))
+    # rows padded, columns as stored: bitwise the unpadded fold
+    ref_rows = igd_ref.igd_fold_ref(xp[:, :d], yp, ap, wp[:d], loss=loss)
+    assert np.array_equal(np.asarray(ref_rows), np.asarray(ref_raw))
+    # rows and columns padded: fp32 fold tolerance on the real columns
+    ref_padded = igd_ref.igd_fold_ref(xp, yp, ap, wp, loss=loss)
+    np.testing.assert_allclose(np.asarray(ref_padded[:d]),
+                               np.asarray(ref_raw), rtol=1e-5, atol=1e-6)
     # and the padded tail of the model never moves off its zero init
     assert np.array_equal(
         np.asarray(ref_padded[d:]), np.zeros(xp.shape[1] - d, np.float32)

@@ -310,6 +310,30 @@ def test_persistent_cache_invalidates_on_different_table(tmp_path):
     assert e2.stats["plans_computed"] == 1
 
 
+def test_plan_key_names_the_platform(tmp_path, monkeypatch):
+    """A plan probed on one platform is no plan for another: a PlanStore
+    entry written under the CPU reads as a miss on a TPU (the probed
+    rates and the kernel choice would be the CPU's)."""
+    from repro.engine import executor
+
+    q = _q(synthetic.dense_classification(RNG, 128, 4))
+    dev = jax.local_devices()[0]
+    e1 = engine.Engine(plan_store=serve.PlanStore(str(tmp_path)))
+    e1.explain(q)
+    key = e1._query_plan_key(q)
+    assert key[-2:] == (dev.platform, dev.device_kind)
+    assert e1.plan_store.load(key, q) is not None
+    monkeypatch.setattr(
+        executor, "device_key",
+        lambda: (jax.local_device_count(), "tpu", "TPU v5 lite"),
+    )
+    e2 = engine.Engine(plan_store=serve.PlanStore(str(tmp_path)))
+    assert e2.plan_store.load(e2._query_plan_key(q), q) is None
+    e2.explain(q)
+    assert e2.stats["plan_disk_hits"] == 0
+    assert e2.stats["plans_computed"] == 1
+
+
 def test_fingerprint_catches_interior_reorder():
     """A same-multiset, interior-only reordering (label-clustered vs
     shuffled — exactly the statistic the planner keys on) must change

@@ -194,6 +194,23 @@ def test_nonconvex_task_caps_sharded_plans():
     assert lmf_ks == {planner.NONCONVEX_SHARD_CAP}
 
 
+def test_shard_probe_picks_a_count_that_divides_the_table():
+    """The planner enumerates only shard counts that divide the table, so
+    the mesh probe must measure such a count: a 2,052-row table (4 x 513,
+    not a multiple of 8) is probed at k=4 on its 2,048-row slab, not at
+    k=8 (which left Forest's 581,012 rows with no sharded candidate)."""
+    from repro.engine import probes
+
+    n = 2052
+    data = synthetic.dense_classification(RNG, n, 4)
+    agg = uda.IGDAggregate(
+        tasks.LogisticRegression(dim=4), igd.diminishing(0.5, decay=n)
+    )
+    slab = jax.tree.map(lambda x: x[:probes.SHARD_PROBE_ROWS], data)
+    shard = probes._probe_sharded(agg, slab, agg.initialize(RNG), n)
+    assert set(shard) == {4}
+
+
 def test_invalid_sharded_hints_and_plans_are_rejected():
     data = synthetic.dense_classification(RNG, 96, 4)
     eng = engine.Engine()
@@ -321,35 +338,59 @@ def test_dryrun_import_no_longer_mutates_env():
 
 
 # ---------------------------------------------------------------------------
-# persistent compilation cache opt-in
+# persistent compilation cache
 # ---------------------------------------------------------------------------
 
 
-def test_xla_cache_enabled_by_env(tmp_path):
-    path = str(tmp_path / "xla_cache")
+@pytest.fixture
+def _cache_on():
+    """The suite runs with JAX's cache switch off; these tests turn it
+    on and restore jax's process-global cache config afterwards."""
+    was_on = jax.config.jax_enable_compilation_cache
     old_dir = jax.config.jax_compilation_cache_dir
     old_state = dict(xla_cache._state)
-    try:
-        assert xla_cache.maybe_enable(env={xla_cache.ENV_VAR: path})
-        assert jax.config.jax_compilation_cache_dir == path
-        assert xla_cache.status()["path"] == path
-        # the engine constructor path goes through maybe_enable and an
-        # executable lands in the cache on compile
-        eng = engine.Engine()
-        data = synthetic.dense_classification(RNG, 64, 4)
-        eng.run(_q(data, epochs=1))
-        assert os.listdir(path), "no executable was persisted"
-    finally:
-        # the cache dir is process-global jax config: restore it so the
-        # rest of the suite doesn't write into a deleted tmp_path
-        jax.config.update("jax_compilation_cache_dir", old_dir)
-        xla_cache._state.update(old_state)
+    jax.config.update("jax_enable_compilation_cache", True)
+    yield
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    jax.config.update("jax_compilation_cache_dir", old_dir)
+    xla_cache._state.update(old_state)
+    from jax.experimental.compilation_cache import compilation_cache
+
+    compilation_cache.reset_cache()
 
 
-def test_xla_cache_disabled_without_env():
-    assert xla_cache.maybe_enable(env={}) == (
-        xla_cache.status()["path"] is not None
+def test_xla_cache_enabled_by_env(_cache_on, tmp_path):
+    """JAX_COMPILATION_CACHE_DIR wins, and the module sets no directory
+    of its own: jax read the variable itself."""
+    path = str(tmp_path / "from_env")
+    before = jax.config.jax_compilation_cache_dir
+    assert xla_cache.maybe_enable(env={xla_cache.ENV_VAR: path})
+    assert xla_cache.status() == {"path": path, "error": None}
+    assert jax.config.jax_compilation_cache_dir == before
+
+
+def test_xla_cache_disabled_without_env(_cache_on, tmp_path, monkeypatch):
+    """Without the variable the cache sits at a fixed, gitignored path
+    in the checkout (redirected here so the test writes no cache files
+    into the tree), and the engine's executables land in it."""
+    root = os.path.join(os.path.dirname(__file__), "..")
+    assert xla_cache.DEFAULT_DIR == os.path.normpath(
+        os.path.join(root, ".jax_cache")
     )
+    with open(os.path.join(root, ".gitignore")) as f:
+        assert ".jax_cache/" in f.read().split()
+    path = str(tmp_path / "jax_cache")
+    monkeypatch.setattr(xla_cache, "DEFAULT_DIR", path)
+    assert xla_cache.maybe_enable(env={})
+    assert jax.config.jax_compilation_cache_dir == path
+    assert xla_cache.status() == {"path": path, "error": None}
+    eng = engine.Engine()
+    eng.run(_q(synthetic.dense_classification(RNG, 64, 4), epochs=1))
+    assert os.listdir(path), "no executable was persisted"
+    # JAX's switch off: nothing is enabled
+    jax.config.update("jax_enable_compilation_cache", False)
+    xla_cache._state["path"] = None
+    assert not xla_cache.maybe_enable(env={})
 
 
 # ---------------------------------------------------------------------------
@@ -386,7 +427,7 @@ def test_sharded_on_forced_mesh_is_placement_independent():
     env["PYTHONPATH"] = os.path.join(
         os.path.dirname(__file__), "..", "src"
     )
-    env.pop("JAX_PLATFORMS", None)
+    env["JAX_PLATFORMS"] = "cpu"  # a forced-host-device mesh, never the chip
     out = subprocess.run(
         [sys.executable, "-c", _SCRIPT_MESH], env=env,
         capture_output=True, text=True, timeout=600,
