@@ -48,7 +48,7 @@ np.testing.assert_allclose(np.asarray(res.model), w, rtol=1e-5, atol=1e-6)
 
 # -- the kernel wall is instrumented ----------------------------------------
 snap = obs.metrics.snapshot()
-assert any("engine.kernel_us_per_epoch" in k for k in snap), sorted(snap)
+assert snap["engine.epoch.grad_s"]["count"] > 0, sorted(snap)
 
 # -- xla_fold forced == default, bit for bit --------------------------------
 ref = eng.run(q(ordering="clustered", scheme="serial"))

@@ -36,7 +36,7 @@ eng = engine.Engine()
 with obs.tracing() as rec:
     eng.run(q())
 names = {s["name"] for s in rec.spans}
-for expected in ("engine.run", "engine.compile", "epoch"):
+for expected in ("engine.run", "engine.compile", "engine.epoch"):
     assert expected in names, (expected, names)
 with tempfile.TemporaryDirectory() as tmp:
     jsonl = os.path.join(tmp, "trace.jsonl")

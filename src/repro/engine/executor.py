@@ -414,15 +414,21 @@ def _execute(
     grad_s = 0.0
     converged = False
     epoch = 0
-    kernel_impl = program_lib.plan_implementation(plan)
     for epoch in range(1, query.epochs + 1):
-        with obs.span("epoch", index=epoch):
+        # engine.sync wraps each wait for the device, so on a profiler's
+        # timeline the device's idle time inside engine.epoch but outside
+        # engine.order and engine.sync is the host's own work per epoch
+        with obs.span("engine.epoch", index=epoch):
             t0 = time.perf_counter()
             if streaming:
                 examples = data  # the chunk stream IS the stored order
             else:
-                examples, perm_rng = ordering.order(data, n, epoch, perm_rng)
-                jax.block_until_ready(examples)
+                with obs.span("engine.order"):
+                    examples, perm_rng = ordering.order(
+                        data, n, epoch, perm_rng
+                    )
+                    with obs.span("engine.sync"):
+                        jax.block_until_ready(examples)
             t1 = time.perf_counter()
             perm_rng, sub = jax.random.split(perm_rng)
             if plan.scheme == "mrs":
@@ -431,23 +437,15 @@ def _execute(
                 )
                 # swap: the memory worker cycles last epoch's reservoir
                 carry = (state, buf_b, buf_a, jnp.bool_(True))
-            elif kernel_impl != "xla_fold":
-                # the kernel wall gets its own span so drift/SLO and
-                # attribution see the implementation axis, not just a
-                # generic epoch
-                with obs.span("engine.kernel", implementation=kernel_impl):
-                    state = compiled.epoch_fn(state, examples, sub)
-                    jax.block_until_ready(state)
             else:
                 state = compiled.epoch_fn(state, examples, sub)
-            jax.block_until_ready(state)
+            with obs.span("engine.sync"):
+                jax.block_until_ready(state)
             t2 = time.perf_counter()
         shuffle_s += t1 - t0
         grad_s += t2 - t1
         obs.metrics.observe("engine.epoch.shuffle_s", t1 - t0)
         obs.metrics.observe("engine.epoch.grad_s", t2 - t1)
-        if kernel_impl != "xla_fold":
-            obs.metrics.observe("engine.kernel_us_per_epoch", (t2 - t1) * 1e6)
         # A stop rule needs the per-epoch objective; without one, a single
         # evaluation after the last epoch suffices (full_loss scans the
         # whole table — not free on the serving path).

@@ -261,6 +261,9 @@ class Ticket:
     accepted: bool
     reject_reason: Optional[str] = None
     submit_s: float = 0.0
+    # when a pump took the ticket off the queue (queue wait = dequeue_s -
+    # submit_s); None until then, and for a ticket refused at admission
+    dequeue_s: Optional[float] = None
     done_s: Optional[float] = None
     result: Optional[executor.EngineResult] = None
     # a query that failed planning/execution completes with the error
@@ -279,6 +282,32 @@ class Ticket:
     def latency_s(self) -> Optional[float]:
         """Queue wait + execution (submit -> completion)."""
         return None if self.done_s is None else self.done_s - self.submit_s
+
+
+def _pump_record(
+    wall: float, cpu: float, lanes: int, depth: int, max_wait: float,
+    jax0: Tuple[int, ...], spans: List[dict],
+) -> Dict[str, Any]:
+    """``ServingEngine.slowest_pump``: one pump's wall and main-thread
+    CPU seconds, its lanes, the queue depth and worst queue wait when it
+    took its group, the JAX compile events during it (deltas of
+    ``obs.compile_counts``), and (name, seconds) of the spans the flight
+    ring recorded inside it, in completion order."""
+    traces, lowerings, compiles, loads = (
+        b - a for a, b in zip(jax0, obs.compile_counts())
+    )
+    return {
+        "wall_s": wall,
+        "cpu_s": cpu,
+        "lanes": lanes,
+        "queue_depth": depth,
+        "max_wait_s": max_wait,
+        "traces": traces,
+        "lowerings": lowerings,
+        "compiles": compiles,
+        "cache_loads": loads,
+        "spans": [(s["name"], s["dur"]) for s in spans],
+    }
 
 
 # ---------------------------------------------------------------------------
@@ -346,6 +375,10 @@ class ServingEngine:
             "singleton_queries": 0,
             "failed_queries": 0,
         }
+        # the longest pump so far (see pump()), also live in /snapshot as
+        # the serve.slowest_pump gauge
+        self.slowest_pump: Optional[Dict[str, Any]] = None
+        obs.metrics.gauge("serve.slowest_pump", fn=lambda: self.slowest_pump)
 
     # -- admission --------------------------------------------------------
 
@@ -409,9 +442,20 @@ class ServingEngine:
 
     def pump(self) -> int:
         """Serve the queue head (plus everything batchable with it).
-        Returns the number of queries completed."""
+        Returns the number of queries completed.
+
+        A pump longer than every one before it replaces
+        ``slowest_pump``, the record that names which side a stall was
+        on: its wall against the main thread's CPU time (host work), the
+        JAX traces, lowerings, compiles and cache loads it waited for,
+        and the spans the flight ring recorded inside it."""
         if not self._queue:
             return 0
+        w0, c0 = time.perf_counter(), time.thread_time()
+        jax0 = obs.compile_counts()
+        ring = flight_lib.get()
+        mark = ring.appended if ring is not None else 0
+        depth = len(self._queue)
         head = self._queue.popleft()
         self._queued_per_task[head.query.task] -= 1
         group = [head]
@@ -436,6 +480,7 @@ class ServingEngine:
             group.extend(matches)
         dequeued = time.perf_counter()
         for t in group:
+            t.dequeue_s = dequeued
             obs.metrics.observe(
                 f"serve.queue_wait_s.{t.query.task}", dequeued - t.submit_s
             )
@@ -487,6 +532,12 @@ class ServingEngine:
         # not sit inside the fused call's wall
         if self.slo is not None:
             self.slo.maybe_evaluate()
+        wall = time.perf_counter() - w0
+        if self.slowest_pump is None or wall > self.slowest_pump["wall_s"]:
+            self.slowest_pump = _pump_record(
+                wall, time.thread_time() - c0, len(group), depth, max_wait,
+                jax0, ring.since(mark) if ring is not None else [],
+            )
         return len(group)
 
     def drain(self) -> int:
@@ -510,11 +561,13 @@ class ServingEngine:
         t0 = time.perf_counter()
         with obs.span("serve.assemble"):
             assembled = assemble()
-            jax.block_until_ready(assembled)
+            with obs.span("engine.sync"):
+                jax.block_until_ready(assembled)
         t1 = time.perf_counter()
         with obs.span("serve.execute"):
             executed = execute(assembled)
-            jax.block_until_ready(executed)
+            with obs.span("engine.sync"):
+                jax.block_until_ready(executed)
         t2 = time.perf_counter()
         obs.metrics.observe("serve.assembly_s", t1 - t0)
         obs.metrics.observe("serve.execute_s", t2 - t1)

@@ -100,10 +100,12 @@ def _minibatch_kernel(x_ref, y_ref, alpha_ref, w0_ref, wout_ref, wscr, *,
         wout_ref[...] = wscr[...]
 
 
-def _fold_call(kernel, x, y, alpha, w0, *, loss: str, y_alpha_space,
-               interpret: bool):
+def _fold_call(kernel, x, y, alpha, w0, *, name: str, loss: str,
+               y_alpha_space, interpret: bool):
     """Shared pallas_call plumbing. x: [N, D] f32 (N % TILE == 0,
-    D % 128 == 0), y/alpha: [N], w0: [D] -> final w [D].
+    D % 128 == 0), y/alpha: [N], w0: [D] -> final w [D]. ``name`` is the
+    kernel's name in HLO, and so in a device trace, whether the call is
+    jitted alone or vmapped.
 
     Layouts the TPU compiler accepts: the model is a [1, D] block (and a
     [1, D] VMEM scratch carried across the sequential grid), and y/alpha
@@ -131,6 +133,7 @@ def _fold_call(kernel, x, y, alpha, w0, *, loss: str, y_alpha_space,
         out_shape=jax.ShapeDtypeStruct((1, d), jnp.float32),
         scratch_shapes=[pltpu.VMEM((1, d), jnp.float32)],
         interpret=interpret,
+        name=name,
     )(
         x,
         y.reshape(n_tiles, 1, TILE),
@@ -143,8 +146,9 @@ def _fold_call(kernel, x, y, alpha, w0, *, loss: str, y_alpha_space,
 def igd_fold(x, y, alpha, w0, *, loss: str = "lr", interpret: bool = False):
     """Sequential IGD over all n examples; y and alpha are read as
     scalars from SMEM, one per row."""
-    return _fold_call(_igd_kernel, x, y, alpha, w0, loss=loss,
-                      y_alpha_space=pltpu.SMEM, interpret=interpret)
+    return _fold_call(_igd_kernel, x, y, alpha, w0, name="igd_serial",
+                      loss=loss, y_alpha_space=pltpu.SMEM,
+                      interpret=interpret)
 
 
 def igd_fold_minibatch(x, y, alpha, w0, *, loss: str = "lr",
@@ -152,5 +156,6 @@ def igd_fold_minibatch(x, y, alpha, w0, *, loss: str = "lr",
     """Minibatch variant: one gradient step per TILE (mean gradient),
     margins computed with an MXU matmul; y and alpha are [1, TILE]
     vectors in VMEM."""
-    return _fold_call(_minibatch_kernel, x, y, alpha, w0, loss=loss,
-                      y_alpha_space=None, interpret=interpret)
+    return _fold_call(_minibatch_kernel, x, y, alpha, w0,
+                      name="igd_minibatch", loss=loss, y_alpha_space=None,
+                      interpret=interpret)

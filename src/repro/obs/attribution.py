@@ -15,12 +15,14 @@ phase                 span names
                       (admission wait is not a span — the
                       serving pump stamps it on its group span)
 ``assemble``          ``serve.assemble``, ``shard.place``,
-                      ``engine.materialize``
+                      ``engine.materialize``, ``engine.order``
 ``compile``           ``engine.compile``, ``program.build``,
                       ``probe.calibrate``
-``execute``           ``serve.execute``, ``epoch``,
+``execute``           ``serve.execute``, ``engine.epoch``,
                       ``shard.block``, ``engine.loss``
 ``merge``             ``shard.merge``
+(its parent's)        ``engine.sync``: a wait for the device is
+                      charged to the phase whose work it waits for
 ``other``             everything else (incl. root self time)
 ====================  =======================================
 
@@ -41,15 +43,18 @@ PHASE_OF = {
     "serve.assemble": "assemble",
     "shard.place": "assemble",
     "engine.materialize": "assemble",
+    "engine.order": "assemble",
     "engine.compile": "compile",
     "program.build": "compile",
     "probe.calibrate": "compile",
     "serve.execute": "execute",
-    "epoch": "execute",
+    "engine.epoch": "execute",
     "shard.block": "execute",
     "engine.loss": "execute",
     "shard.merge": "merge",
 }
+# spans charged to the phase of the on-path span above them
+INHERIT = frozenset({"engine.sync"})
 
 
 def critical_path(
@@ -142,10 +147,12 @@ def attribute(
         return None
     root = path[0]
     phase_s: Dict[str, float] = {}
+    phase = "other"
     for i, span in enumerate(path):
         child_dur = path[i + 1]["dur"] if i + 1 < len(path) else 0.0
         self_s = max(span["dur"] - child_dur, 0.0)
-        phase = PHASE_OF.get(span["name"], "other")
+        if span["name"] not in INHERIT:
+            phase = PHASE_OF.get(span["name"], "other")
         phase_s[phase] = phase_s.get(phase, 0.0) + self_s
     queue_wait = float(root.get("attrs", {}).get("queue_wait_s") or 0.0)
     if queue_wait > 0:

@@ -4,8 +4,9 @@ Full tracing (:mod:`repro.obs.trace`) is off by default because an
 unbounded recorder cannot be left on a serving loop. The flight
 recorder is the production counterpart: a fixed-size deque of completed
 span records that IS cheap enough to leave on — per span it pays one
-``Span`` allocation, two ``perf_counter`` reads and a lock-guarded
-deque append (the ``maxlen`` bound makes eviction free), so the last N
+``Span`` allocation, two ``perf_counter`` reads, the profiler's
+"collecting?" check and a lock-guarded deque append (the ``maxlen``
+bound makes eviction free), so the last N
 spans of engine/serve activity are always dumpable *after* something
 went wrong, without anyone having enabled tracing *before*.
 
@@ -31,6 +32,7 @@ fact.
 from __future__ import annotations
 
 import collections
+import itertools
 import time
 from typing import List, Optional
 
@@ -53,12 +55,27 @@ class FlightRecorder(trace.Recorder):
             raise ValueError(f"flight capacity must be >= 1, got {capacity}")
         self.capacity = capacity
         self.spans = collections.deque(maxlen=capacity)
+        # records ever appended (evicted ones included): a reader marks
+        # it, and ``since(mark)`` returns what came after
+        self.appended = 0
+
+    def _append(self, record: dict) -> None:
+        with self._lock:
+            self.spans.append(record)
+            self.appended += 1
 
     def push(self, record: dict) -> None:
         """Mirror an already-closed span record into the ring (used by
         the full tracer so the window stays continuous while tracing)."""
+        self._append(record)
+
+    def since(self, mark: int) -> List[dict]:
+        """The records appended after ``appended`` read ``mark`` that the
+        ring still holds, oldest first."""
         with self._lock:
-            self.spans.append(record)
+            held = len(self.spans)
+            k = min(self.appended - mark, held)
+            return list(itertools.islice(self.spans, held - k, None))
 
     def snapshot_spans(self) -> List[dict]:
         """A consistent copy of the ring, oldest first."""

@@ -1,21 +1,35 @@
-"""The span tracer: structured wall-clock spans with near-zero cost off.
+"""The span tracer: structured wall-clock spans, on the device trace's
+clock as well.
 
 Design constraints, in order:
 
-1. **The disabled path is a no-op.** ``span()`` is called on the warm
-   serving path (per epoch, per batch); when tracing is off it must cost
-   one module-global check and return a shared null context manager —
-   no allocation beyond the kwargs dict, no branching downstream.
-   ``benchmarks/engine_bench.py`` guards this with an overhead row
-   (spans-per-warm-run × measured disabled-span cost must stay under 2%
-   of the warm wall).
+1. **The disabled path is nearly a no-op.** ``span()`` is called on the
+   warm serving path (per epoch, per batch); with tracing and the
+   flight ring off it costs two module-global checks and one call to
+   the profiler's "is a trace collecting" check, and returns the shared
+   null context manager. While a ``jax.profiler`` trace collects, it
+   returns a ``jax.profiler.TraceAnnotation`` of the span's name instead
+   (outside a trace an annotation records nothing, so the null span is
+   the same thing, cheaper). ``benchmarks/engine_bench.py`` guards this
+   with an overhead row (spans-per-warm-run × measured disabled-span
+   cost must stay under 2% of the warm wall).
 2. **One process-global recorder.** Every subsystem (executor, serving
    front-end, sharded driver, probes, program compiler) traces into the
    same recorder, so one export shows where a query's time actually
    went across layers.
-3. **Boring, greppable output.** JSONL (one span per line, fixed
+3. **One clock with the device.** Every span, recorded or not, enters
+   a ``TraceAnnotation`` of its name while a profiler collects, so the
+   profiler puts the program's host spans on the same timeline as the
+   device's operations (one xplane): an idle stretch of the device can
+   be named by the span the host was in.
+4. **Boring, greppable output.** JSONL (one span per line, fixed
    schema) for machines; Chrome-trace JSON (``chrome://tracing`` /
    Perfetto) for eyeballs.
+
+Span names carry their layer as a dotted prefix (``engine.epoch``,
+``serve.pump``, ``probe.calibrate``, ``program.build``, ``shard.block``)
+so a trace tells them from the benchmark harness's ``bench.*``
+annotations and from the runtime's own events.
 
 Typical use::
 
@@ -42,6 +56,8 @@ import threading
 import time
 from typing import Any, Dict, List, Optional
 
+import jax
+
 # The fixed JSONL schema the smoke test validates: key -> required type.
 JSONL_SCHEMA = {
     "name": str,
@@ -58,7 +74,7 @@ class Span:
     """One live span (context manager). ``set(**attrs)`` attaches
     attributes at any point before exit."""
 
-    __slots__ = ("_rec", "name", "attrs", "id", "parent", "ts", "_t0")
+    __slots__ = ("_rec", "name", "attrs", "id", "parent", "ts", "_t0", "_ann")
 
     def __init__(self, rec: "Recorder", name: str, attrs: Dict[str, Any]):
         self._rec = rec
@@ -75,11 +91,14 @@ class Span:
 
     def __enter__(self) -> "Span":
         self._rec._open(self)
+        self._ann = _annotation(self.name)
+        self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc) -> bool:
         dur = time.perf_counter() - self._t0
+        self._ann.__exit__(*exc)
         self._rec._close(self, dur)
         return False
 
@@ -100,6 +119,23 @@ class _NullSpan:
 
 
 NULL_SPAN = _NullSpan()
+
+
+class _Annotation(jax.profiler.TraceAnnotation):
+    """A span with no recorder while a profiler collects: its name on the
+    profiler's host timeline, and ``set`` a no-op."""
+
+    def set(self, **attrs) -> "_Annotation":
+        return self
+
+
+_collecting = jax.profiler.TraceAnnotation.is_enabled
+
+
+def _annotation(name: str):
+    """The profiler annotation of ``name`` while a trace collects, else
+    the shared null span (an annotation outside a trace records nothing)."""
+    return _Annotation(name) if _collecting() else NULL_SPAN
 
 
 class Recorder:
@@ -144,13 +180,16 @@ class Recorder:
             "tid": threading.get_ident() & 0xFFFF,
             "attrs": span.attrs,
         }
-        with self._lock:
-            self.spans.append(record)
+        self._append(record)
         # mirror into the always-on flight ring (when installed) so the
         # last-N window stays continuous across tracing on/off
         fl = _FLIGHT
         if fl is not None and fl is not self:
             fl.push(record)
+
+    def _append(self, record: dict) -> None:
+        with self._lock:
+            self.spans.append(record)
 
     # -- queries ----------------------------------------------------------
 
@@ -211,16 +250,18 @@ _FLIGHT = None
 
 def span(name: str, **attrs):
     """A wall-clock span context manager. THE tracing entry point —
-    with tracing disabled and no flight recorder installed this is two
-    module-global checks returning the shared null span (the no-op
-    closure the warm path relies on); with the flight recorder on, the
-    span records into its bounded ring instead (priced by
-    ``flight.recording_span_cost`` and bench-guarded)."""
+    with tracing disabled and no flight recorder installed it does no
+    recorder work: it returns the profiler annotation of ``name`` while
+    a ``jax.profiler`` trace collects, else the shared null span (the
+    near no-op the warm path relies on), and ``set`` is a no-op on
+    either; with the flight recorder on, the span records into its
+    bounded ring instead (priced by ``flight.recording_span_cost`` and
+    bench-guarded). A recorded span enters the same annotation."""
     if _ENABLED:
         return Span(_RECORDER, name, attrs)
     if _FLIGHT is not None:
         return Span(_FLIGHT, name, attrs)
-    return NULL_SPAN
+    return _annotation(name)
 
 
 def _install_flight(recorder) -> None:

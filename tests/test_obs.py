@@ -14,7 +14,7 @@ from repro import engine, obs
 from repro.core import tracecount
 from repro.data import synthetic
 from repro.engine import serve
-from repro.obs import drift, metrics, trace
+from repro.obs import drift, flight, metrics, trace
 
 RNG = jax.random.PRNGKey(0)
 
@@ -81,6 +81,104 @@ def test_disabled_span_cost_measures_off_path_only():
     with obs.tracing():
         with pytest.raises(RuntimeError):
             trace.disabled_span_cost(iters=10)
+
+
+def _profiled_host_events(log_dir, body):
+    """Run ``body`` under a CPU ``jax.profiler`` trace; the host plane's
+    (name, start ns, end ns) events."""
+    import glob
+    import os
+
+    from jax._src.profiler import ProfileData
+
+    jax.profiler.start_trace(str(log_dir))
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(os.path.join(str(log_dir), "**", "*.xplane.pb"),
+                        recursive=True)
+    events = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name == "/host:CPU":
+            for line in plane.lines:
+                events.extend(
+                    (ev.name, ev.start_ns, ev.start_ns + ev.duration_ns)
+                    for ev in line.events
+                )
+    return events
+
+
+@pytest.mark.parametrize("mode", ["tracing", "flight", "off"])
+def test_spans_are_profiler_annotations_in_every_mode(tmp_path, mode):
+    """Recorded, in the flight ring only, or neither: a span shows up in
+    a profiler trace under its name, nested as it ran; ``set`` works;
+    and with both off no recorder gains anything."""
+    ring = flight.enable(capacity=8) if mode == "flight" else None
+    rec = obs.enable(trace.Recorder())
+    if mode != "tracing":
+        obs.disable()  # the recorder stays installed, and must stay empty
+
+    def body():
+        with obs.span("test.outer", layer="test") as outer:
+            outer.set(extra=1)
+            with obs.span("test.inner") as inner:
+                assert inner.set(k=2) is inner
+                jnp.zeros(4).block_until_ready()
+
+    events = _profiled_host_events(tmp_path, body)
+    obs.disable()
+    (outer,) = [e for e in events if e[0] == "test.outer"]
+    (inner,) = [e for e in events if e[0] == "test.inner"]
+    assert outer[1] <= inner[1] and inner[2] <= outer[2]
+    if mode == "tracing":
+        assert [s["name"] for s in rec.spans] == ["test.inner", "test.outer"]
+        assert rec.spans[1]["attrs"] == {"layer": "test", "extra": 1}
+    elif mode == "flight":
+        assert [s["name"] for s in ring.snapshot_spans()] == \
+            ["test.inner", "test.outer"]
+        assert len(rec) == 0
+    else:
+        assert len(rec) == 0 and flight.get() is None
+    # outside a profiler trace the fully-off path is the shared null span
+    if mode == "off":
+        assert obs.span("x") is trace.NULL_SPAN
+
+
+def test_compile_listener_counts_traces_and_compiles():
+    """One jax.monitoring listener feeds the jax.* counters; it was
+    registered once and keeps counting after the registry is reset (the
+    autouse fixture resets it between tests)."""
+    for _ in range(2):
+        before = obs.compile_counts()
+        jax.jit(lambda x: x * 7 + 1)(jnp.ones(5)).block_until_ready()
+        after = obs.compile_counts()
+        traces, lowerings, compiles, loads = (
+            b - a for a, b in zip(before, after)
+        )
+        assert traces >= 1 and lowerings >= 1 and compiles >= 1
+        assert loads == 0  # the tests run without the persistent cache
+        snap = obs.metrics.snapshot("jax.")
+        assert snap["jax.compiles"]["value"] >= compiles
+        assert snap["jax.compile_s"]["count"] == snap["jax.compiles"]["value"]
+        assert snap["jax.trace_s"]["sum"] > 0
+        obs.reset_metrics()
+        assert obs.metrics.snapshot("jax.") == {}
+
+
+def test_a_persistent_cache_hit_counts_as_a_load():
+    """The backend-compile event that follows a cache retrieval is booked
+    as a cache load, so no second lands in two histograms."""
+    from repro import obs as obs_pkg
+
+    obs_pkg._on_duration(obs_pkg._CACHE_HIT, 0.01)
+    obs_pkg._on_duration(obs_pkg._BACKEND, 0.25)
+    obs_pkg._on_duration(obs_pkg._BACKEND, 2.0)
+    snap = obs.metrics.snapshot("jax.")
+    assert snap["jax.cache_loads"]["value"] == 1
+    assert snap["jax.cache_load_s"]["sum"] == 0.25
+    assert snap["jax.compiles"]["value"] == 1
+    assert snap["jax.compile_s"]["sum"] == 2.0
 
 
 def test_jsonl_export_validates_and_chrome_trace_loads(tmp_path):

@@ -134,6 +134,23 @@ def test_flight_ring_is_bounded():
     assert [s["attrs"]["i"] for s in spans] == [6, 7, 8, 9]  # last N win
 
 
+def test_flight_since_returns_what_came_after_a_mark():
+    fl = flight.enable(capacity=4)
+    with obs.span("before"):
+        pass
+    mark = fl.appended
+    assert fl.since(mark) == []
+    for i in range(3):
+        with obs.span("after", i=i):
+            pass
+    assert [s["attrs"]["i"] for s in fl.since(mark)] == [0, 1, 2]
+    for i in range(3, 6):
+        with obs.span("after", i=i):
+            pass
+    # six came after the mark; the ring holds the last four of them
+    assert [s["attrs"]["i"] for s in fl.since(mark)] == [2, 3, 4, 5]
+
+
 def test_flight_mirrors_full_tracing():
     fl = flight.enable(capacity=8)
     with obs.tracing() as rec:
@@ -192,7 +209,7 @@ def test_critical_path_follows_longest_children():
         _span("serve.assemble", 1, 0, 0.0, 0.2),
         _span("serve.execute", 2, 0, 0.2, 0.7),
         _span("engine.compile", 3, 2, 0.2, 0.5),
-        _span("epoch", 4, 2, 0.7, 0.1),
+        _span("engine.epoch", 4, 2, 0.7, 0.1),
     ]
     path = attribution.critical_path(spans)
     assert [s["name"] for s in path] == \
@@ -208,6 +225,25 @@ def test_critical_path_follows_longest_children():
     assert rep.share("compile") == pytest.approx(0.4)
     text = rep.describe()
     assert "compile 40%" in text and "serve.pump" in text
+
+
+def test_a_sync_is_charged_to_the_phase_it_waits_in():
+    """engine.sync has no phase of its own: the device wait inside an
+    epoch is execute time, inside the ordering step assemble time."""
+    spans = [
+        _span("engine.run", 0, None, 0.0, 1.0),
+        _span("engine.epoch", 1, 0, 0.0, 0.9),
+        _span("engine.order", 2, 1, 0.0, 0.1),
+        _span("engine.sync", 3, 2, 0.02, 0.08),
+        _span("engine.sync", 4, 1, 0.2, 0.6),
+    ]
+    rep = attribution.attribute(spans, root_name="engine.run")
+    assert [n for n, _ in rep.path] == \
+        ["engine.run", "engine.epoch", "engine.sync"]
+    assert rep.phase_s["execute"] == pytest.approx(0.9)  # 0.3 self + 0.6
+    assert rep.phase_s["other"] == pytest.approx(0.1)
+    order = attribution.attribute(spans[2:4], root_name="engine.order")
+    assert order.phase_s == pytest.approx({"assemble": 0.1})
 
 
 def test_attribution_round_trips_and_handles_empty():

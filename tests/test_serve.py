@@ -2,6 +2,8 @@
 equivalence, the persistent plan cache's warm start, and the executor's
 MRS double-buffer swap."""
 
+import time
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -267,6 +269,69 @@ def test_failed_query_completes_with_error_and_does_not_kill_the_queue():
     assert "no_such_ordering" in bad.error
     assert good.done and good.result is not None and good.error is None
     assert srv.stats["failed_queries"] == 1
+
+
+def test_tickets_stamp_when_they_left_the_queue():
+    data = synthetic.dense_classification(RNG, 64, 4)
+    srv = serve.ServingEngine(
+        serve.ServeConfig(max_queue=2, max_per_task=8, max_batch=1)
+    )
+    tickets = [srv.submit(_q(data, seed=s)) for s in range(3)]
+    assert tickets[2].dequeue_s is None  # refused at admission
+    assert all(t.dequeue_s is None for t in tickets[:2])
+    srv.drain()
+    first, second = tickets[:2]
+    for t in (first, second):
+        assert t.submit_s <= t.dequeue_s <= t.done_s
+    # one lane per pump: the second waited while the first ran
+    assert second.dequeue_s >= first.done_s
+    assert tickets[2].dequeue_s is None
+
+
+def test_slowest_pump_keeps_the_longest_pump_and_its_spans():
+    """The record is replaced only by a longer pump, and names what the
+    pump waited on: main-thread CPU against the wall, compile events,
+    and the spans the flight ring recorded inside it."""
+    from repro import obs
+    from repro.obs import export
+
+    data = synthetic.dense_classification(RNG, 64, 4)
+    srv = serve.ServingEngine(serve.ServeConfig(max_batch=1))
+    assert srv.slowest_pump is None
+    srv.submit(_q(data, seed=0))
+    srv.pump()  # cold: plans and compiles
+    cold = srv.slowest_pump
+    assert cold["lanes"] == 1 and cold["queue_depth"] == 1
+    assert cold["traces"] > 0 and cold["compiles"] > 0
+    names = [n for n, _ in cold["spans"]]
+    assert "serve.pump" in names and "engine.epoch" in names
+    assert "engine.sync" in names and "engine.order" in names
+
+    srv.submit(_q(data, seed=1))
+    srv.pump()  # warm, and shorter: the record stays
+    assert srv.slowest_pump is cold
+
+    run = srv.engine.run
+    stall = 2.0 * cold["wall_s"]
+
+    def stalled(query):
+        with obs.span("test.stall"):
+            time.sleep(stall)
+        return run(query)
+
+    srv.engine.run = stalled
+    for s in (2, 3):
+        srv.submit(_q(data, seed=s))
+    srv.pump()
+    slow = srv.slowest_pump
+    assert slow is not cold and slow["wall_s"] >= stall
+    assert slow["queue_depth"] == 2 and slow["lanes"] == 1
+    assert slow["cpu_s"] < slow["wall_s"] - 0.5 * stall  # it slept
+    assert slow["compiles"] == 0 and slow["cache_loads"] == 0
+    assert dict(slow["spans"])["test.stall"] >= stall
+    assert [n for n, _ in slow["spans"]][-1] == "serve.pump"
+    snap = export.snapshot_payload()["metrics"]["serve.slowest_pump"]
+    assert snap["value"] is slow
 
 
 # ---------------------------------------------------------------------------

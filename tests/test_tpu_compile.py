@@ -121,3 +121,26 @@ def test_fused_kernel_lane_compiles_for_v5e(one_chip, ordering):
         args = (states, data,
                 _spec(one_chip, (BATCH, FOREST_ROWS), jnp.int32))
     _assert_kernel_fits(jax.jit(lane).lower(*args).compile())
+
+
+@pytest.mark.parametrize("kernel,lanes", [
+    ("igd_serial", 0), ("igd_serial", 2), ("igd_minibatch", 0),
+])
+def test_the_kernel_is_named_in_the_compiled_program(one_chip, kernel,
+                                                     lanes):
+    """A profiler names a device operation after its HLO instruction, so
+    the pallas_call's ``name=`` is what a trace shows: the same stem for
+    the kernel alone and vmapped over query lanes."""
+    fold = {"igd_serial": igd_ops.igd_fold,
+            "igd_minibatch": igd_ops.igd_fold_minibatch}[kernel]
+
+    def lane(x, y, a, w):
+        return fold(x, y, a, w, loss="lr", interpret=False)
+
+    n = 4096
+    x, y, a, w = _table_specs(one_chip, n=n)
+    if lanes:
+        lane = jax.vmap(lane, in_axes=(None, None, None, 0))
+        w = _spec(one_chip, (lanes, FOREST_DIM))
+    text = jax.jit(lane).lower(x, y, a, w).compile().as_text()
+    assert f"%{kernel}." in text
