@@ -7,6 +7,7 @@ import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
+from repro.data import synthetic
 from repro.kernels.attention import ops as attn_ops
 from repro.kernels.decode import ops as dec_ops
 from repro.kernels.igd_fused import kernel as igd_kernel
@@ -132,6 +133,82 @@ def test_igd_escape_hatch_matches_kernel(op):
     wh = op(x, y, alpha, w0, loss="lsq", use_kernel=False)
     assert wh.shape == (72,)
     np.testing.assert_allclose(np.asarray(wk), np.asarray(wh),
+                               rtol=2e-4, atol=2e-5)
+
+
+@pytest.mark.parametrize("loss", ["svm", "lr"])
+def test_igd_fold_forest_shaped_parity(loss):
+    """The serial kernel takes its margins from a block's Gram matrix but
+    still updates the model row by row, in row order, as the sequential
+    fold does. On a Forest-shaped table (54 features, a planted separator
+    with overlapping classes, shuffled, alpha0 = 0.2 diminishing) the
+    margins differ from the fold's only by rounding, which moves no
+    hinge: the svm model must come out bit for bit the fold's. A model
+    accumulated any other way (once per block, say) flips hinges near
+    margin 1 and fails here. logreg's smooth loss carries the margins'
+    rounding into the model: 1e-5 of each entry, or of the model's
+    largest entry for entries near zero (where a row-at-a-time fold in
+    the kernel's lane order differs from the reference by 2.7e-5 of the
+    entry)."""
+    n, d = 4096, 54
+    rng = jax.random.PRNGKey(7)
+    data = synthetic.dense_classification(rng, n, d, margin=0.05, noise=0.5)
+    perm = jax.random.permutation(jax.random.fold_in(rng, 3), n)
+    x, y = data["x"][perm], data["y"][perm]
+    alpha = 0.2 / (1.0 + jnp.arange(n, dtype=jnp.float32) / n)
+    w0 = jnp.zeros((d,), jnp.float32)
+    wk = np.asarray(igd_ops.igd_fold(x, y, alpha, w0, loss=loss))
+    wr = np.asarray(igd_ref.igd_fold_ref(x, y, alpha, w0, loss=loss))
+    if loss == "svm":
+        assert np.array_equal(wk, wr)
+    else:
+        np.testing.assert_allclose(wk, wr, rtol=1e-5,
+                                   atol=1e-5 * np.max(np.abs(wr)))
+
+
+def test_igd_svm_hinge_follows_the_sequential_margin():
+    """Where a block margin's rounding and the sequential fold's margin
+    fall on different sides of the hinge, the fold's decision holds. Two
+    entries of w, 2**23 and -2**23, cancel in every row's margin, and
+    each step adds 0.5 to both: the first is absorbed (the spacing of
+    floats there is 1), the second is not. So after one update the fold
+    reads a margin of 0.5 and steps again, where the block's Gram
+    correction reads 1.0 and would not. Every margin here is exact in
+    any order of addition, so the fold's answer is unambiguous, and the
+    kernel's must equal it bit for bit."""
+    n, d = 32, 54
+    x = jnp.zeros((n, d), jnp.float32).at[:, :2].set(1.0)
+    y = jnp.ones((n,), jnp.float32)
+    alpha = jnp.full((n,), 0.5, jnp.float32)
+    w0 = jnp.zeros((d,), jnp.float32).at[0].set(2.0**23).at[1].set(-2.0**23)
+    wk = igd_ops.igd_fold(x, y, alpha, w0, loss="svm")
+    wr = igd_ref.igd_fold_ref(x, y, alpha, w0, loss="svm")
+    assert float(wr[1]) == -2.0**23 + 1.0  # two steps, then margin 1.0
+    assert np.array_equal(np.asarray(wk), np.asarray(wr))
+
+
+@pytest.mark.parametrize("loss", ["lr", "svm", "lsq"])
+@pytest.mark.parametrize("n", [300, 520])
+def test_igd_zero_alpha_rows_inside_a_block_are_noops(loss, n):
+    """Rows with alpha = 0 anywhere in a block, not only in the tail pad,
+    are exact no-ops of the block recurrence: their c is 0, so neither
+    the model nor the block's later margins move. Their contents
+    therefore cannot reach the result, bit for bit; and the kernel
+    agrees with the sequential fold over the remaining rows alone."""
+    d = 72
+    x, y, alpha, w0 = _igd_inputs(n, d)
+    idx = np.arange(n)
+    # runs of zero steps inside blocks and across block boundaries
+    dead = (idx % 7 == 3) | ((idx >= 40) & (idx < 75)) | (idx == n - 1)
+    alpha = jnp.where(jnp.asarray(dead), 0.0, alpha)
+    wk = igd_ops.igd_fold(x, y, alpha, w0, loss=loss)
+    x_other = jnp.where(jnp.asarray(dead)[:, None], 3.0 * x[::-1], x)
+    y_other = jnp.where(jnp.asarray(dead), -y, y)
+    wk_other = igd_ops.igd_fold(x_other, y_other, alpha, w0, loss=loss)
+    assert np.array_equal(np.asarray(wk), np.asarray(wk_other))
+    live = ~dead
+    wr = igd_ref.igd_fold_ref(x[live], y[live], alpha[live], w0, loss=loss)
+    np.testing.assert_allclose(np.asarray(wk), np.asarray(wr),
                                rtol=2e-4, atol=2e-5)
 
 
