@@ -59,6 +59,13 @@ class IGDAggregate(UDA):
     (defaulting to ``jax.grad`` of ``example_loss``); this class provides the
     generic four functions. Per the paper, the only task-specific logic
     lives inside the transition's gradient computation.
+
+    Where the task names the rows an example reads (``example_rows``) and
+    the prox is the identity, the transition is row-sparse: it gathers
+    those rows, takes the gradient on the one-row slices and writes back
+    only the updated rows, so a step costs the rows it touches and not the
+    whole model. A prox acts on every coordinate at every step, so a task
+    with one keeps the dense step.
     """
 
     task: Any
@@ -69,10 +76,31 @@ class IGDAggregate(UDA):
         model = self.task.init_model(rng)
         return IGDState(model, jnp.int32(0), jnp.float32(0.0))
 
+    @property
+    def row_sparse(self) -> bool:
+        """Whether a transition writes only the rows its example reads."""
+        return (
+            self.task.example_rows is not None
+            and self.prox is igd_lib.identity_prox
+        )
+
+    def update_bytes(self) -> int:
+        """Bytes of model one transition writes: one row of each leaf
+        when the transition is row-sparse, else the whole model."""
+        model = jax.eval_shape(self.initialize, jax.random.PRNGKey(0)).model
+        return sum(
+            (leaf.size // leaf.shape[0] if self.row_sparse else leaf.size)
+            * leaf.dtype.itemsize
+            for leaf in jax.tree.leaves(model)
+        )
+
     def transition(self, state: IGDState, example: Example) -> IGDState:
         alpha = self.step_size(state.step)
-        grad = self.task.example_grad(state.model, example)
-        model = igd_lib.igd_step(state.model, grad, alpha, self.prox)
+        if self.row_sparse:
+            model = _row_step(self.task, state.model, example, alpha)
+        else:
+            grad = self.task.example_grad(state.model, example)
+            model = igd_lib.igd_step(state.model, grad, alpha, self.prox)
         return IGDState(model, state.step + 1, state.weight + 1.0)
 
     def merge(self, a: IGDState, b: IGDState) -> IGDState:
@@ -85,6 +113,23 @@ class IGDAggregate(UDA):
 
     def terminate(self, state: IGDState) -> Any:
         return state.model
+
+
+def _row_step(task, model, example, alpha):
+    """The IGD step on the rows one example reads: the same gradient of
+    ``example_loss`` as the dense step, taken on one-row slices, and the
+    same ``w - alpha * g`` on those rows; every other row is left as it
+    is, as the dense step's zero gradient leaves it."""
+    rows, local = task.example_rows(example)
+    sliced = jax.tree.map(
+        lambda leaf, r: jax.lax.dynamic_index_in_dim(leaf, r, 0), model, rows
+    )
+    grad = task.example_grad(sliced, local)
+    new = jax.tree.map(lambda w, g: w - alpha * g, sliced, grad)
+    return jax.tree.map(
+        lambda leaf, r, w: jax.lax.dynamic_update_index_in_dim(leaf, w, r, 0),
+        model, rows, new,
+    )
 
 
 class NullAggregate(UDA):

@@ -18,12 +18,18 @@ prox operator, planning, execution, caching)::
 
 That is the paper's "a few dozen lines" claim made executable — see
 ENGINE.md for the worked example and tests/test_engine.py for the proof.
+
+A query may state its own first step in ``task_args["alpha0"]``
+(:func:`stated_step`): it replaces the catalog schedule's ``alpha0`` for
+that query, for any technique, and never reaches the task's factory.
 """
 
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Callable, Dict, Optional
+import math
+import numbers
+from typing import Any, Callable, Dict, Optional, Tuple
 
 from repro import tasks as tasks_lib
 from repro.core import igd
@@ -70,6 +76,40 @@ class TaskSpec:
 
     def make_task(self, **task_args):
         return self.factory(**task_args)
+
+    def schedule(self, n_examples: int, alpha0: Optional[float] = None):
+        """The step-size schedule over a table of ``n_examples`` rows:
+        the catalog's, with its ``alpha0`` replaced where the query
+        stated one (the kind and the decay stay the catalog's)."""
+        step = self.step_size(n_examples)
+        if alpha0 is None:
+            return step
+        return dataclasses.replace(step, alpha0=alpha0)
+
+
+# The task argument through which a query states its step: the first
+# step size of the technique's schedule (``igd.StepSize.alpha0``).
+STEP_ARG = "alpha0"
+
+
+def stated_step(task_args) -> Tuple[dict, Optional[float]]:
+    """Split a query's ``task_args`` into the task's own arguments and
+    the step the query states (None where it states none)."""
+    args = dict(task_args)
+    if STEP_ARG not in args:
+        return args, None
+    alpha0 = args.pop(STEP_ARG)
+    if (
+        isinstance(alpha0, bool)
+        or not isinstance(alpha0, numbers.Real)
+        or not math.isfinite(alpha0)
+        or alpha0 <= 0
+    ):
+        raise ValueError(
+            f"task_args[{STEP_ARG!r}] must be a positive finite number, "
+            f"got {alpha0!r}"
+        )
+    return args, float(alpha0)
 
 
 _REGISTRY: Dict[str, TaskSpec] = {}

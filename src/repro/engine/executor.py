@@ -106,13 +106,15 @@ class Engine:
         from repro.core import uda as uda_lib
 
         spec = catalog.get(query.task)
-        args = dict(query.task_args)
+        # a stated step replaces the catalog's alpha0 here, the one place
+        # every driver (executor, serve, shard, probes) builds its aggregate
+        args, alpha0 = catalog.stated_step(query.task_args)
         if spec.derive_args is not None:
             args.update(spec.derive_args(args, query.n_examples))
         task = spec.make_task(**args)
         agg = uda_lib.IGDAggregate(
             task,
-            spec.step_size(query.n_examples),
+            spec.schedule(query.n_examples, alpha0),
             prox=spec.prox(task),
         )
         return spec, task, agg
@@ -217,8 +219,9 @@ class Engine:
         if plan is None:
             report = self.explain(query)
             plan = report.chosen
-        with obs.span("engine.run", task=query.task, axes=plan.axes()):
+        with obs.span("engine.run", task=query.task, axes=plan.axes()) as sp:
             compiled = self._compile(query, plan)
+            sp.set(alpha0=compiled.agg.step_size.alpha0)
             return _execute(compiled, query, report)
 
     # -- EXPLAIN ANALYZE ---------------------------------------------------

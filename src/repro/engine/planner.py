@@ -191,6 +191,9 @@ class PlanReport:
     # ordering × parallelism × batch × source); "" on pre-axes entries,
     # re-derived from the chosen plan at describe time
     axes: str = ""
+    # the step-size schedule the run folds with, and whether the query
+    # stated its alpha0 or took the catalog's (:func:`step_line`)
+    step: str = ""
 
     def describe(self) -> str:
         lines = [
@@ -210,6 +213,8 @@ class PlanReport:
         if chosen_note:
             why += f" — {chosen_note}"
         lines.insert(1, f"why    : {why}")
+        if self.step:
+            lines.insert(3, f"step   : {self.step}")
         for c in sorted(self.candidates, key=lambda c: c.cost_seconds)[1:]:
             cost = (
                 "infeasible"
@@ -229,6 +234,7 @@ class PlanReport:
             "clusteredness": self.clusteredness,
             "calibration": self.calibration.to_dict(),
             "axes": self.axes,
+            "step": self.step,
         }
 
     @classmethod
@@ -242,7 +248,24 @@ class PlanReport:
             clusteredness=d["clusteredness"],
             calibration=probes.Calibration.from_dict(d["calibration"]),
             axes=d.get("axes", ""),
+            step=d.get("step", ""),
         )
+
+
+def step_line(query: AnalyticsQuery, agg) -> str:
+    """EXPLAIN's step line: the schedule's rule, its first step and where
+    that step came from (the query's ``task_args`` or the catalog)."""
+    from repro.engine import catalog
+
+    step = agg.step_size
+    origin = (
+        "stated by the query" if catalog.STEP_ARG in query.task_args
+        else "the catalog's"
+    )
+    return (
+        f"{step.kind}, alpha0={step.alpha0:g} ({origin}), "
+        f"decay={step.decay:g} steps"
+    )
 
 
 # ---------------------------------------------------------------------------
@@ -309,6 +332,12 @@ def _conv_multiplier(
             mult *= 1.0 + 0.1 * (plan.num_shards - 1)
     elif plan.scheme == "segmented":
         mult *= 1.0 + 0.1 * (plan.num_segments - 1)  # model-averaging loss
+        if nonconvex:
+            # the singleton scheme averages k segments' factors with no
+            # step compensation, and each segment moved only the rows its
+            # own examples read: the average keeps ~1/k of every row's
+            # progress (and misaligned factors cancel), so ~k x the epochs
+            mult *= plan.num_segments
     elif plan.scheme == "shared_memory":
         mult *= 1.1 if plan.sm_scheme != "lock" else 1.0
     return mult, note
@@ -768,4 +797,5 @@ def plan(query: AnalyticsQuery, agg) -> PlanReport:
         clusteredness=clustered,
         calibration=cal,
         axes=best.plan.axes(batch=batch_axis),
+        step=step_line(query, agg),
     )

@@ -960,6 +960,8 @@ def build_program(
     from repro import obs
 
     obs.metrics.inc("program.builds")
+    # what one transition of the compiled lane body writes to the model
+    obs.metrics.set_gauge("program.update_bytes_per_row", agg.update_bytes())
     with obs.span(
         "program.build", axes=prog.plan.axes() if hasattr(prog.plan, "axes")
         else "", batch=prog.batch,

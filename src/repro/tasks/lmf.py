@@ -2,9 +2,13 @@
 
     min_{L,R}  sum_{(i,j) in Omega} (L_i . R_j - M_ij)^2 + mu ||L,R||_F^2
 
-Per-rating IGD touches only row L_i and row R_j — ``jax.grad`` through the
-row gathers produces the sparse scatter-add update (the Gemulla et al. /
-Bismarck LMF transition). Regularization is localized to the touched rows,
+Per-rating IGD touches only row L_i and row R_j (the Gemulla et al. /
+Bismarck LMF transition): ``example_rows`` names those two rows, and the
+engine's transition (``core.uda.IGDAggregate``) gathers them, takes
+``jax.grad`` of ``example_loss`` on the two rows and writes back only
+them, so a step costs O(rank) whatever the size of the factor tables.
+``jax.grad`` over the whole model (``example_grad``) gives the same rows
+and zeros everywhere else. Regularization is localized to the touched rows,
 scaled down by the rows' expected appearance counts (the standard weighted
 trick), so the transition stays O(rank): summing the per-example penalty
 over one epoch recovers ~``mu * ||L,R||_F^2`` exactly once, matching
@@ -51,6 +55,10 @@ class LowRankMF(Task):
             "L": self.init_scale * jax.random.normal(kl, (self.n_rows, self.rank), jnp.float32),
             "R": self.init_scale * jax.random.normal(kr, (self.n_cols, self.rank), jnp.float32),
         }
+
+    def example_rows(self, ex):
+        local = dict(ex, i=jnp.zeros_like(ex["i"]), j=jnp.zeros_like(ex["j"]))
+        return {"L": ex["i"], "R": ex["j"]}, local
 
     def example_loss(self, m, ex):
         li = m["L"][ex["i"]]
