@@ -67,11 +67,13 @@ class TaskSpec:
     # quality penalty at k<=4 — the stratified DSGD schedule that fixes
     # this properly is a ROADMAP item).
     nonconvex: bool = False
-    # Loss name in the fused-IGD Pallas kernel's dispatch table
-    # (kernels/igd_fused: "lr" | "svm" | "lsq"), for techniques whose
+    # Loss name in the fused-IGD Pallas kernels' dispatch table
+    # (kernels/igd_fused: "lr" | "svm" | "lsq" for techniques whose
     # transition is exactly margin -> scale -> axpy on a dense (x, y)
-    # row. Unset means the implementation axis stays at xla_fold for
-    # this technique (structured models, sparse rows, custom prox).
+    # row; "lmf" for the row kernel's two-row factorization step over
+    # (i, j, v) ratings). Unset means the implementation axis stays at
+    # xla_fold for this technique (structured models, sparse rows,
+    # custom prox).
     kernel_loss: Optional[str] = None
 
     def make_task(self, **task_args):
@@ -134,8 +136,9 @@ def register_task(
     sharded plan axis for it (model averaging is unsafe at high shard
     counts; default: convex).
     ``kernel_loss``: fused-IGD kernel loss name ("lr"/"svm"/"lsq") when
-    the transition matches the kernel's margin/scale/axpy shape (default:
-    none — implementation axis stays xla_fold)."""
+    the transition matches the dense kernel's margin/scale/axpy shape,
+    "lmf" for the row kernel's factorization step (default: none —
+    implementation axis stays xla_fold)."""
     step = step_size or (lambda n: igd.diminishing(0.1, decay=max(n, 1)))
 
     def deco(cls):
@@ -238,6 +241,7 @@ register_task(
     step_size=lambda n: igd.diminishing(0.1, decay=max(n, 1)),
     derive_args=_lmf_derive_degrees,
     nonconvex=True,
+    kernel_loss="lmf",
 )(tasks_lib.LowRankMF)
 
 register_task(

@@ -630,11 +630,14 @@ def enumerate_plans(query: AnalyticsQuery, unroll: int, cal=None) -> List[Plan]:
                 f"conflicting scheme hint {hints['scheme']!r}"
             )
         hints["scheme"] = "serial"
-        if cal is not None and not getattr(cal, "impl_per_row", {}):
+        probed = getattr(cal, "impl_per_row", {})
+        if cal is not None and impl_hint not in probed:
             raise ValueError(
                 f"implementation={impl_hint!r} forced for a query whose "
-                "aggregate is not kernel-eligible (catalog kernel_loss + "
-                "identity prox + dense (x, y) rows — see "
+                "aggregate is not kernel-eligible for it (catalog "
+                "kernel_loss + identity prox + dense (x, y) rows, or lmf's "
+                "(i, j, v) ratings, which lower through pallas_fused "
+                f"alone; probed lanes: {sorted(probed) or 'none'} — see "
                 "program.kernel_eligibility)"
             )
     if hints.get("parallelism") == "sharded" and hints.get("scheme") not in (

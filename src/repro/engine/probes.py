@@ -210,7 +210,8 @@ def calibrate(agg, data, key: Tuple, *, unrolls=(1, 8)) -> Calibration:
     # SAME slab as the xla fold: a rate amortized over a different row
     # count would re-bias the exact ranking the axis exists to measure.
     # Kernel-eligible aggregates only (catalog kernel_loss + identity
-    # prox + dense (x, y) rows) — everything else plans pure xla_fold.
+    # prox + dense (x, y) rows, or lmf's (i, j, v) ratings with tables
+    # that fit the row kernel's VMEM) — everything else plans xla_fold.
     impl_per_row = _probe_implementations(agg, slab, state0, rows)
 
     # (f) sharded local-SGD blocks on the live device mesh (multi-device
@@ -243,17 +244,26 @@ def calibrate(agg, data, key: Tuple, *, unrolls=(1, 8)) -> Calibration:
 def _probe_implementations(agg, slab, state0, rows: int) -> Dict[str, float]:
     """Time the fused-IGD kernel lanes (seconds/row) for the
     implementation axis. Empty dict when the aggregate is not
-    kernel-eligible or the slab is not dense (x, y) rows — the planner
-    then never enumerates a pallas_* candidate."""
+    kernel-eligible or the slab is not the kernel's rows — dense (x, y)
+    rows for the dense kernel, (i, j, v) ratings for the row kernel
+    (``lmf``, whose one lowering is pallas_fused) — the planner then
+    never enumerates a pallas_* candidate."""
     import functools
 
     from repro.engine import program as program_lib
 
     loss, _why = program_lib.kernel_eligibility(agg.task, agg)
+    if loss is None or not isinstance(slab, dict):
+        return {}
+    if loss == program_lib.ROW_KERNEL_LOSS:
+        if set(slab) != {"i", "j", "v"}:
+            return {}
+        # the whole lane, as the epoch runs it: step sizes, the tables'
+        # padding to the kernel's layout, the kernel, the unpadding
+        lane = jax.jit(program_lib.kernel_lane_fold(agg, loss))
+        return {"pallas_fused": time_call(lane, state0, slab) / rows}
     if (
-        loss is None
-        or not isinstance(slab, dict)
-        or "x" not in slab or "y" not in slab
+        "x" not in slab or "y" not in slab
         or getattr(slab["x"], "ndim", 0) != 2
     ):
         return {}

@@ -36,9 +36,11 @@ The axes
 * **implementation** — ``xla_fold`` (the generic ``uda.fold`` scan) or
   ``pallas_fused``/``pallas_minibatch`` (the fused-IGD Pallas kernel,
   ``repro.kernels.igd_fused``: model hot in VMEM while example tiles
-  stream past — the paper's Bismarck inner loop as a real kernel).
-  Serial lane bodies only; eligibility is a catalog property
-  (``TaskSpec.kernel_loss`` + identity prox — see
+  stream past — the paper's Bismarck inner loop as a real kernel; for
+  ``lmf`` the row kernel, both factor tables in VMEM while ratings
+  stream past). Serial lane bodies only; eligibility is a catalog
+  property (``TaskSpec.kernel_loss`` + identity prox, and for ``lmf`` a
+  row-sparse transition whose tables fit the kernel's VMEM — see
   :func:`kernel_eligibility`). The planner prices it from micro-probes
   (``probes.Calibration.impl_per_row``). Carried by
   ``Plan.implementation``.
@@ -72,6 +74,7 @@ from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro.core import mrs as mrs_lib, ordering as ordering_lib
@@ -101,11 +104,15 @@ SHARD_MODES = {
 # The implementation axis: how a serial lane body is lowered.
 #   xla_fold        — the generic unified-aggregate scan (uda.fold)
 #   pallas_fused    — kernels/igd_fused per-tuple IGD (ref.py oracle:
-#                     bit-order-identical to the scan, fp32 tolerance)
+#                     bit-order-identical to the scan, fp32 tolerance);
+#                     lmf: the per-rating row kernel (rows.py)
 #   pallas_minibatch— one mean-gradient step per 256-row tile: a
 #                     DIFFERENT algorithm (hint-only; never auto-chosen)
 IMPLEMENTATIONS = ("xla_fold", "pallas_fused", "pallas_minibatch")
 PALLAS_IMPLEMENTATIONS = ("pallas_fused", "pallas_minibatch")
+# the catalog kernel_loss that lowers through the row kernel
+# (kernels/igd_fused/rows.py) instead of the dense one
+ROW_KERNEL_LOSS = "lmf"
 
 
 def canonical_ordering(name: str) -> str:
@@ -119,12 +126,20 @@ def plan_implementation(plan) -> str:
 
 
 def kernel_eligibility(task, agg) -> Tuple[Optional[str], str]:
-    """(kernel loss name, "") when the aggregate can lower through the
+    """(kernel loss name, "") when the aggregate can lower through a
     fused-IGD kernel, else (None, reason). Eligibility is a catalog
     property: the task's exact class must be registered with a
-    ``kernel_loss`` (lr/svm/lsq) AND the aggregate must carry the
-    identity prox — the kernel's transition has no prox hook, so an L1
-    ball or simplex projection would silently be skipped."""
+    ``kernel_loss`` AND the aggregate must carry the identity prox — the
+    kernels' transitions have no prox hook, so an L1 ball or simplex
+    projection would silently be skipped.
+
+    ``lr``/``svm``/``lsq`` take the dense kernel (``igd_fused.kernel``)
+    over ``{x, y}`` rows. ``lmf`` takes the row kernel
+    (``igd_fused.rows``) over ``{i, j, v}`` ratings: the aggregate's
+    transition must be row-sparse, and both factor tables, padded to
+    the kernel's layout, must fit its VMEM budget
+    (``rows.VMEM_TABLE_BUDGET``), since it holds them in VMEM for the
+    whole epoch."""
     from repro.core import igd as igd_lib
     from repro.engine import catalog
 
@@ -132,13 +147,28 @@ def kernel_eligibility(task, agg) -> Tuple[Optional[str], str]:
     if loss is None:
         return None, (
             f"task {type(task).__name__} has no kernel_loss in the catalog "
-            "(only dense lr/svm/lsq transitions match the kernel)"
+            "(only dense lr/svm/lsq and row-sparse lmf transitions match "
+            "a kernel)"
         )
     if agg.prox is not igd_lib.identity_prox:
         return None, (
             "the fused kernel's transition has no prox hook; this "
             "aggregate carries a non-identity prox"
         )
+    if loss == ROW_KERNEL_LOSS:
+        from repro.kernels.igd_fused import rows
+
+        if not agg.row_sparse:
+            return None, (
+                "the row kernel steps the two rows a rating reads; this "
+                "aggregate's transition is dense"
+            )
+        need = rows.table_bytes(task.n_rows, task.n_cols, task.rank)
+        if need > rows.VMEM_TABLE_BUDGET:
+            return None, (
+                f"the padded factor tables take {need:,} B of VMEM, over "
+                f"the row kernel's budget of {rows.VMEM_TABLE_BUDGET:,} B"
+            )
     return loss, ""
 
 
@@ -148,6 +178,12 @@ def require_kernel_loss(task, agg, implementation: str) -> str:
         raise ValueError(
             f"implementation={implementation!r} needs a kernel-eligible "
             f"aggregate: {why}"
+        )
+    if loss == ROW_KERNEL_LOSS and implementation != "pallas_fused":
+        raise ValueError(
+            f"implementation={implementation!r} needs a kernel-eligible "
+            "aggregate: the row kernel (lmf) has only the sequential "
+            "pallas_fused lowering"
         )
     return loss
 
@@ -377,17 +413,21 @@ def permuted_lane(agg, unroll: int):
 
 def kernel_lane_fold(agg, loss: str, *, minibatch: bool = False,
                      interpret: Optional[bool] = None):
-    """The serial lane body lowered through the fused-IGD Pallas kernel:
-    ``(state, ex) -> state`` over a dense ``{"x": [n, d], "y": [n]}``
-    epoch stream, advancing step/weight exactly like ``uda.fold`` (one
-    per example). The per-example step sizes are the sequential
-    schedule's exact values — transition i reads ``step_size(step0 + i)``
-    and ``StepSize`` is elementwise over the step vector, so the kernel
-    sees the same alphas the scan would have computed one at a time.
+    """The serial lane body lowered through a fused-IGD Pallas kernel:
+    ``(state, ex) -> state`` over one epoch stream, advancing step/weight
+    exactly like ``uda.fold`` (one per example). The per-example step
+    sizes are the sequential schedule's exact values — transition i
+    reads ``step_size(step0 + i)`` and ``StepSize`` is elementwise over
+    the step vector, so the kernel sees the same alphas the scan would
+    have computed one at a time. A dense ``{"x": [n, d], "y": [n]}``
+    stream takes the dense kernel; ``loss="lmf"`` a ``{"i", "j", "v"}``
+    ratings stream and the row kernel (:func:`_row_lane`).
     ``interpret=None`` picks per backend (interpret on CPU, compiled on
     TPU — ``igd_fused.ops.default_interpret``)."""
     from repro.kernels.igd_fused import ops as igd_ops
 
+    if loss == ROW_KERNEL_LOSS:  # one lowering (require_kernel_loss)
+        return _row_lane(agg, interpret)
     op = igd_ops.igd_fold_minibatch if minibatch else igd_ops.igd_fold
 
     def lane(state, ex):
@@ -396,6 +436,31 @@ def kernel_lane_fold(agg, loss: str, *, minibatch: bool = False,
         alphas = agg.step_size(state.step + jnp.arange(n))
         model = op(x, y, alphas, state.model, loss=loss, interpret=interpret)
         return uda_lib.IGDState(model, state.step + n, state.weight + n)
+
+    return lane
+
+
+def _row_lane(agg, interpret: Optional[bool]):
+    """The lmf lane over the row kernel: the regularizer's scale per row,
+    ``mu / degree``, is computed in float32 as ``jax.grad`` of
+    ``LowRankMF.example_loss`` computes it."""
+    from repro.kernels.igd_fused import ops as igd_ops
+
+    task = agg.task
+    mu = np.float32(task.mu)
+    c_row = float(mu / np.float32(task.mean_row_degree))
+    c_col = float(mu / np.float32(task.mean_col_degree))
+
+    def lane(state, ex):
+        n = ex["i"].shape[0]
+        alphas = agg.step_size(state.step + jnp.arange(n))
+        left, right = igd_ops.lmf_fold(
+            ex["i"], ex["j"], ex["v"], alphas,
+            state.model["L"], state.model["R"],
+            c_row=c_row, c_col=c_col, interpret=interpret,
+        )
+        return uda_lib.IGDState({"L": left, "R": right}, state.step + n,
+                                state.weight + n)
 
     return lane
 

@@ -17,7 +17,13 @@ zero alpha kills it). Padded columns are zero, so they add nothing to
 the margin, but they change the length of the margin's reduction and
 with it the order of its additions: D padding agrees with the unpadded
 fold to fp32 tolerance, not bit for bit (pinned by
-tests/test_kernels.py)."""
+tests/test_kernels.py).
+
+``lmf_fold`` is the row-sparse kernel for low-rank factorization
+(``rows.py``): it pads the factor tables to the kernel's layout and the
+ratings to its tile, and unpads what comes back. Under ``jax.vmap`` (the
+fused serving lanes, the sharded blocks' lanes) it runs the kernel once
+per lane, in turn: each call holds one lane's tables in VMEM."""
 
 from __future__ import annotations
 
@@ -25,9 +31,11 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.custom_batching import custom_vmap
 
 from repro.kernels.igd_fused import kernel as K
 from repro.kernels.igd_fused import ref as R
+from repro.kernels.igd_fused import rows as RK
 
 
 def default_interpret() -> bool:
@@ -82,3 +90,52 @@ def igd_fold_minibatch(x, y, alpha, w0, *, loss="lr", interpret=None,
     out = K.igd_fold_minibatch(xp, yp, ap, wp, loss=loss,
                                interpret=_resolve(interpret))
     return out[:d]
+
+
+def _lmf_padded(i, j, v, alpha, left, right, *, c_row, c_col, interpret):
+    pad = (-i.shape[0]) % RK.TILE  # alpha = 0 -> padded ratings are no-ops
+    cols = [jnp.pad(c, (0, pad)) for c in (i, j, v, alpha)]
+    tables = [
+        jnp.pad(t, [(0, p - s) for p, s in zip(RK.padded_shape(*t.shape),
+                                               t.shape)])
+        for t in (left, right)
+    ]
+    out = RK.lmf_fold(*cols, *tables, c_row=c_row, c_col=c_col,
+                      interpret=interpret)
+    return tuple(o[:t.shape[0], :t.shape[1]] for o, t in
+                 zip(out, (left, right)))
+
+
+@functools.lru_cache(maxsize=None)
+def _lmf_lanes(c_row: float, c_col: float, interpret: bool):
+    @custom_vmap
+    def fold(i, j, v, alpha, left, right):
+        return _lmf_padded(i, j, v, alpha, left, right, c_row=c_row,
+                           c_col=c_col, interpret=interpret)
+
+    @fold.def_vmap
+    def _lanes(axis_size, in_batched, *args):
+        # one kernel call per lane: the tables of one lane fill the VMEM
+        # budget the kernel is admitted under, so lanes run in turn
+        del axis_size
+
+        def lane(xs):
+            xs = iter(xs)
+            return fold(*(next(xs) if b else a
+                          for a, b in zip(args, in_batched)))
+
+        out = jax.lax.map(lane, [a for a, b in zip(args, in_batched) if b])
+        return out, (True, True)
+
+    return fold
+
+
+@functools.partial(jax.jit, static_argnames=("c_row", "c_col", "interpret"))
+def lmf_fold(i, j, v, alpha, left, right, *, c_row: float, c_col: float,
+             interpret=None):
+    """Sequential row-sparse lmf IGD over ratings (i, j, v) with
+    per-rating step sizes alpha, from factor tables left [U, r] and
+    right [M, r]; ``c_row`` / ``c_col`` are mu over the mean user / movie
+    degree. Returns the updated (left, right)."""
+    return _lmf_lanes(c_row, c_col, _resolve(interpret))(
+        i, j, v, alpha, left, right)

@@ -46,3 +46,21 @@ def igd_fold_minibatch_ref(x, y, alpha, w0, *, loss: str = "lr", tile: int = 256
 
     w, _ = jax.lax.scan(body, w0, (xt, yt, at))
     return w
+
+
+def lmf_fold_ref(i, j, v, alpha, left, right, *, c_row: float, c_col: float):
+    """Sequential row-sparse lmf IGD via lax.scan: rating k steps row
+    i[k] of ``left`` and row j[k] of ``right`` by ``jax.grad`` of the
+    task's per-rating loss, in the same form (``c = mu / degree``)."""
+
+    def body(lr, ex):
+        left, right = lr
+        ii, jj, vv, a = ex
+        l, r = left[ii], right[jj]
+        e2 = 2.0 * (jnp.dot(l, r) - vv)
+        gl = (c_row * l + c_row * l) + e2 * r
+        gr = (c_col * r + c_col * r) + e2 * l
+        return (left.at[ii].set(l - a * gl), right.at[jj].set(r - a * gr)), None
+
+    (left, right), _ = jax.lax.scan(body, (left, right), (i, j, v, alpha))
+    return left, right

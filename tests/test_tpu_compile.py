@@ -144,3 +144,49 @@ def test_the_kernel_is_named_in_the_compiled_program(one_chip, kernel,
         w = _spec(one_chip, (lanes, FOREST_DIM))
     text = jax.jit(lane).lower(x, y, a, w).compile().as_text()
     assert f"%{kernel}." in text
+
+
+# MovieLens-1M at the benchmark's rank: the row kernel's tables
+ML_USERS, ML_MOVIES, ML_RATINGS, ML_RANK = 6040, 3706, 1_000_209, 50
+
+
+def _row_fold(i, j, v, a, left, right):
+    return igd_ops.lmf_fold(i, j, v, a, left, right, c_row=1e-4,
+                            c_col=1e-4, interpret=False)
+
+
+def _rating_specs(sharding, n):
+    return (_spec(sharding, (n,), jnp.int32), _spec(sharding, (n,), jnp.int32),
+            _spec(sharding, (n,)), _spec(sharding, (n,)))
+
+
+@pytest.mark.parametrize("lanes", [0, 2])
+def test_row_kernel_compiles_for_v5e(one_chip, lanes):
+    """The lmf row kernel at the MovieLens-1M shape, both factor tables
+    held in VMEM, alone and vmapped over query lanes (which run the
+    kernel lane by lane); named like the dense serial kernel."""
+    fold = _row_fold
+    tables = ((ML_USERS, ML_RANK), (ML_MOVIES, ML_RANK))
+    if lanes:
+        fold = jax.vmap(fold, in_axes=(None,) * 4 + (0, 0))
+        tables = tuple((lanes,) + t for t in tables)
+    compiled = jax.jit(fold).lower(
+        *_rating_specs(one_chip, ML_RATINGS),
+        *(_spec(one_chip, t) for t in tables),
+    ).compile()
+    _assert_kernel_fits(compiled)
+    assert "%igd_serial." in compiled.as_text()
+
+
+def test_row_kernel_compiles_at_its_vmem_budget(one_chip):
+    """Tables that take the whole budget (``rows.VMEM_TABLE_BUDGET``) still
+    compile: the budget is one the chip's compiler accepts."""
+    from repro.kernels.igd_fused import rows
+
+    side = rows.VMEM_TABLE_BUDGET // (2 * 128 * 4)
+    assert rows.table_bytes(side, side, 128) == rows.VMEM_TABLE_BUDGET
+    compiled = jax.jit(_row_fold).lower(
+        *_rating_specs(one_chip, 4096),
+        _spec(one_chip, (side, 128)), _spec(one_chip, (side, 128)),
+    ).compile()
+    _assert_kernel_fits(compiled)
