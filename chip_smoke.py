@@ -226,8 +226,8 @@ def phase_auto(eng, data, seed: int, clock: CompileClock):
         f"{k}={v * 1e6:.4f}" for k, v in sorted(
             dict(rates, xla_fold=min(report.calibration.fold_per_row.values())
                  ).items())))
-    check({"pallas_fused", "pallas_minibatch"} <= set(rates),
-          "the implementation probes did not time the Pallas kernels")
+    check({"pallas_fused"} <= set(rates),
+          "the implementation probe did not time the Pallas kernel")
     compiled = kernel_probe_compiled(n, data["x"].shape[1])
     say("auto", f"kernel probes compiled={compiled} "
                 f"(interpret={igd_ops.default_interpret()})")

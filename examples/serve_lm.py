@@ -13,7 +13,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import get_arch
-from repro.launch.serve import make_decode_step
+from repro.launch.dryrun import make_decode_step
 from repro.models import lm
 
 

@@ -1,5 +1,6 @@
 """Distribution layer: partition-spec derivation, activation-sharding
-constraints, cross-shard collectives for the LM substrate, and the
-shared-nothing data-parallel IGD blocks behind ``repro.engine.shard``."""
-
-from repro.dist import collectives, data_parallel, sharding  # noqa: F401
+constraints and cross-shard collectives for the LM substrate
+(``sharding``, ``collectives``), and the shared-nothing data-parallel IGD
+blocks behind ``repro.engine.shard`` (``data_parallel``). Import each
+submodule by name: the package imports none of them, so the analytics
+engine loads ``data_parallel`` alone."""

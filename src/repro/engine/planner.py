@@ -4,8 +4,7 @@ The planner enumerates the physical-plan space the paper studies as
 independent knobs —
 
     ordering policy (§3.2)  x  execution scheme (§3.3: serial fold,
-    shared-nothing segmented fold, shared-memory concurrency; §3.4:
-    buffered MRS)  x  scan unroll —
+    shared-nothing segmented fold; §3.4: buffered MRS)  x  scan unroll —
 
 and picks the cheapest plan under a cost model whose constants are
 measured by micro-probes (``repro.engine.probes``) rather than assumed.
@@ -39,8 +38,6 @@ def _is_stored(query: "AnalyticsQuery") -> bool:
 
 ORDERINGS = ("clustered", "shuffle_once", "shuffle_always")
 SEGMENT_CANDIDATES = (2, 4, 8)
-SM_SCHEMES = ("lock", "aig", "nolock")
-SM_WORKERS = 8
 MRS_RATIO = 2
 # Merge periods enumerated for sharded plans (filtered to divisors of the
 # epoch budget so a run compiles ONE block length).
@@ -52,11 +49,6 @@ NONCONVEX_SHARD_CAP = 4
 # Convergence-penalty cap for a fully label-clustered scan (paper Fig. 5:
 # orders of magnitude more epochs; 50x is enough to always reject it).
 CLUSTERED_PENALTY_CAP = 50.0
-# Per-step overhead factor of the shared-memory simulator (ravel/unravel +
-# ring-buffer bookkeeping around each transition). The simulator runs on
-# ONE device — its cost model claims no parallel speedup (it exists to
-# reproduce Fig. 9's convergence behavior, not to be fast).
-SM_OVERHEAD = 3.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -65,10 +57,8 @@ class Plan:
     plan cache key."""
 
     ordering: str  # clustered | shuffle_once | shuffle_always
-    scheme: str  # serial | segmented | shared_memory | mrs
+    scheme: str  # serial | segmented | mrs
     num_segments: int = 1
-    sm_scheme: str = "nolock"
-    sm_workers: int = SM_WORKERS
     mrs_buffer: int = 0
     mrs_ratio: int = MRS_RATIO
     unroll: int = 1
@@ -90,8 +80,7 @@ class Plan:
     # -- the implementation axis (repro.kernels.igd_fused) -----------------
     # xla_fold: the generic uda.fold scan. pallas_fused: the fused-IGD
     # kernel's per-tuple lane (probe-priced against the scan for
-    # kernel-eligible serial plans). pallas_minibatch: one mean-gradient
-    # step per tile — different algorithm semantics, hint-only.
+    # kernel-eligible serial plans).
     implementation: str = "xla_fold"
 
     def axes(self, batch: str = "1") -> str:
@@ -123,11 +112,6 @@ class Plan:
             ex = (
                 f"segmented fold ({self.num_segments} shared-nothing "
                 f"segments, merge=model-averaging, unroll={self.unroll})"
-            )
-        elif self.scheme == "shared_memory":
-            ex = (
-                f"shared-memory fold ({self.sm_scheme}, "
-                f"{self.sm_workers} workers)"
             )
         else:
             ex = (
@@ -338,8 +322,6 @@ def _conv_multiplier(
             # own examples read: the average keeps ~1/k of every row's
             # progress (and misaligned factors cancel), so ~k x the epochs
             mult *= plan.num_segments
-    elif plan.scheme == "shared_memory":
-        mult *= 1.1 if plan.sm_scheme != "lock" else 1.0
     return mult, note
 
 
@@ -365,7 +347,7 @@ def cost_components(
 
     The implementation component carries the serial singleton lane
     body's compute, priced at the probed rate of the chosen lowering
-    (``cal.impl_per_row`` for pallas_*, ``cal.fold_per_row`` for
+    (``cal.impl_per_row`` for pallas_fused, ``cal.fold_per_row`` for
     xla_fold); parallelism is 0 there — the axes split the same total,
     they don't double-count it. Every other scheme/parallelism keeps
     its compute under parallelism (their lane body is defined by the
@@ -374,8 +356,8 @@ def cost_components(
     fold_row = cal.fold_per_row.get(plan.unroll) or min(
         cal.fold_per_row.values()
     )
-    impl = getattr(plan, "implementation", "xla_fold")
-    impl_rates = getattr(cal, "impl_per_row", {})
+    impl = plan.implementation
+    impl_rates = cal.impl_per_row
 
     # -- ordering axis: the cost of imposing the scan order --------------
     if plan.parallelism == "sharded":
@@ -432,8 +414,6 @@ def cost_components(
         per_epoch = cal.seg_per_row_at(plan.num_segments) * n
         per_epoch += cal.merge_seconds * (plan.num_segments - 1)
         parallelism = per_epoch * est_epochs
-    elif plan.scheme == "shared_memory":
-        parallelism = SM_OVERHEAD * fold_row * n * est_epochs
     else:  # mrs: 1 I/O step + ratio memory steps per streamed tuple
         parallelism = fold_row * n * (1 + plan.mrs_ratio) * est_epochs
 
@@ -586,7 +566,7 @@ def _sharded_plans(
 
 
 def enumerate_plans(query: AnalyticsQuery, unroll: int, cal=None) -> List[Plan]:
-    SCHEMES = ("serial", "segmented", "shared_memory", "mrs")
+    SCHEMES = ("serial", "segmented", "mrs")
     PARALLELISMS = ("singleton", "sharded")
     hints = dict(query.hints)
     if "ordering" in hints:
@@ -630,14 +610,13 @@ def enumerate_plans(query: AnalyticsQuery, unroll: int, cal=None) -> List[Plan]:
                 f"conflicting scheme hint {hints['scheme']!r}"
             )
         hints["scheme"] = "serial"
-        probed = getattr(cal, "impl_per_row", {})
-        if cal is not None and impl_hint not in probed:
+        if cal is not None and impl_hint not in cal.impl_per_row:
             raise ValueError(
                 f"implementation={impl_hint!r} forced for a query whose "
                 "aggregate is not kernel-eligible for it (catalog "
                 "kernel_loss + identity prox + dense (x, y) rows, or lmf's "
-                "(i, j, v) ratings, which lower through pallas_fused "
-                f"alone; probed lanes: {sorted(probed) or 'none'} — see "
+                "(i, j, v) ratings; probed lanes: "
+                f"{sorted(cal.impl_per_row) or 'none'} — see "
                 "program.kernel_eligibility)"
             )
     if hints.get("parallelism") == "sharded" and hints.get("scheme") not in (
@@ -674,11 +653,6 @@ def enumerate_plans(query: AnalyticsQuery, unroll: int, cal=None) -> List[Plan]:
                     plans.extend(
                         Plan(o, "segmented", num_segments=k, unroll=unroll)
                         for k in ks
-                    )
-                elif s == "shared_memory":
-                    plans.extend(
-                        Plan(o, "shared_memory", sm_scheme=sm)
-                        for sm in SM_SCHEMES
                     )
                 elif s == "mrs" and (o == "clustered" or "scheme" in hints):
                     # MRS exists to avoid the shuffle: stream stored order
@@ -727,14 +701,12 @@ def enumerate_plans(query: AnalyticsQuery, unroll: int, cal=None) -> List[Plan]:
         plans = [
             dataclasses.replace(p, implementation=impl_hint) for p in plans
         ]
-    elif impl_hint is None and cal is not None and getattr(
-        cal, "impl_per_row", {}
-    ).get("pallas_fused") is not None:
+    elif impl_hint is None and cal is not None and (
+        "pallas_fused" in cal.impl_per_row
+    ):
         # auto: enumerate the kernel lane next to the scan for serial
         # singleton plans — the probe-derived choice falls out of the
-        # ranking. pallas_minibatch is never auto-chosen (one averaged
-        # step per tile is a different algorithm, not a faster identical
-        # one) and sharded plans keep their mesh-probed xla lanes.
+        # ranking. Sharded plans keep their mesh-probed xla lanes.
         plans.extend([
             dataclasses.replace(p, implementation="pallas_fused")
             for p in plans

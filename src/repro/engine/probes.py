@@ -82,10 +82,10 @@ class Calibration:
     # single-device mesh, where the sharded plan axis does not exist
     shard: Dict[int, ShardPoint] = dataclasses.field(default_factory=dict)
     device_count: int = 1
-    # measured fused-IGD kernel lanes (implementation -> seconds/row:
-    # "pallas_fused", "pallas_minibatch"), probed on the SAME slab as
-    # the xla fold so the implementation-axis ranking compares like with
-    # like; empty when the aggregate is not kernel-eligible
+    # the measured fused-IGD kernel lane ("pallas_fused" -> seconds/row),
+    # probed on the SAME slab as the xla fold so the implementation-axis
+    # ranking compares like with like; empty when the aggregate is not
+    # kernel-eligible
     impl_per_row: Dict[str, float] = dataclasses.field(default_factory=dict)
 
     def best_unroll(self) -> int:
@@ -206,7 +206,7 @@ def calibrate(agg, data, key: Tuple, *, unrolls=(1, 8)) -> Calibration:
         )
         seg_per_row[k_seg] = time_call(seg, state0, slab) / rows
 
-    # (e) the fused-IGD kernel lanes (the implementation axis), on the
+    # (e) the fused-IGD kernel lane (the implementation axis), on the
     # SAME slab as the xla fold: a rate amortized over a different row
     # count would re-bias the exact ranking the axis exists to measure.
     # Kernel-eligible aggregates only (catalog kernel_loss + identity
@@ -242,14 +242,12 @@ def calibrate(agg, data, key: Tuple, *, unrolls=(1, 8)) -> Calibration:
 
 
 def _probe_implementations(agg, slab, state0, rows: int) -> Dict[str, float]:
-    """Time the fused-IGD kernel lanes (seconds/row) for the
+    """Time the fused-IGD kernel lane (seconds/row) for the
     implementation axis. Empty dict when the aggregate is not
     kernel-eligible or the slab is not the kernel's rows — dense (x, y)
     rows for the dense kernel, (i, j, v) ratings for the row kernel
-    (``lmf``, whose one lowering is pallas_fused) — the planner then
-    never enumerates a pallas_* candidate."""
-    import functools
-
+    (``lmf``) — the planner then never enumerates a pallas_fused
+    candidate."""
     from repro.engine import program as program_lib
 
     loss, _why = program_lib.kernel_eligibility(agg.task, agg)
@@ -269,19 +267,14 @@ def _probe_implementations(agg, slab, state0, rows: int) -> Dict[str, float]:
         return {}
     from repro.kernels.igd_fused import ops as igd_ops
 
-    # the sequential schedule's exact per-row alphas, like the kernel lane
+    # the sequential schedule's exact per-row alphas, like the kernel
+    # lane; interpret follows the backend: compiled on a TPU
     alphas = agg.step_size(state0.step + jnp.arange(rows))
-    out = {}
-    for name, op in (
-        ("pallas_fused", igd_ops.igd_fold),
-        ("pallas_minibatch", igd_ops.igd_fold_minibatch),
-    ):
-        # interpret follows the backend: compiled on a TPU
-        fn = functools.partial(op, loss=loss)
-        out[name] = time_call(
-            fn, slab["x"], slab["y"], alphas, state0.model
-        ) / rows
-    return out
+    seconds = time_call(
+        lambda x, y, a, w: igd_ops.igd_fold(x, y, a, w, loss=loss),
+        slab["x"], slab["y"], alphas, state0.model,
+    )
+    return {"pallas_fused": seconds / rows}
 
 
 def _min_of(fn, *args, iters: int = 5) -> float:

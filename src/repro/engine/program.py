@@ -18,11 +18,10 @@ The axes
   the heap, "sequential" otherwise), ``shuffle_once``, or
   ``shuffle_always`` (paper §3.2). Carried by ``Plan.ordering``.
 * **parallelism** — ``singleton`` (one device runs the plan's scheme:
-  serial fold, segmented fold, the shared-memory concurrency
-  *simulator*, or buffered MRS) or ``sharded(k, H)`` (k shared-nothing
-  segments over a device mesh, merge-period-H local SGD — §3.3 at mesh
-  scale). Carried by ``Plan.parallelism``/``num_shards``/
-  ``merge_period``/``shard_devices``.
+  serial fold, segmented fold, or buffered MRS) or ``sharded(k, H)``
+  (k shared-nothing segments over a device mesh, merge-period-H local
+  SGD — §3.3 at mesh scale). Carried by ``Plan.parallelism``/
+  ``num_shards``/``merge_period``/``shard_devices``.
 * **query batching** — ``B`` fused query lanes, each with its own
   threefry rng stream and its own *epoch budget*: every fused run takes
   a ``budgets[B]`` vector and freezes a lane's state once its budget is
@@ -34,7 +33,7 @@ The axes
   stored-table chunk stream via the duck-typed ``Table`` protocol —
   see ``repro.engine.table``). Carried by ``Plan.source``.
 * **implementation** — ``xla_fold`` (the generic ``uda.fold`` scan) or
-  ``pallas_fused``/``pallas_minibatch`` (the fused-IGD Pallas kernel,
+  ``pallas_fused`` (the fused-IGD Pallas kernel,
   ``repro.kernels.igd_fused``: model hot in VMEM while example tiles
   stream past — the paper's Bismarck inner loop as a real kernel; for
   ``lmf`` the row kernel, both factor tables in VMEM while ratings
@@ -63,8 +62,8 @@ Compile counting
 All executables go through ``repro.core.tracecount.counted_jit``: each
 compiled program carries a per-program retrace counter (the cache
 tests' observable) and every retrace also lands in the process-wide
-tally (``tracecount.GLOBAL``), including the standalone
-``run_mrs``/``run_shared_memory`` drivers.
+tally (``tracecount.GLOBAL``), including the standalone drivers in
+``repro.core`` (MRS and the Fig 9 concurrency simulator).
 """
 
 from __future__ import annotations
@@ -78,7 +77,7 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro.core import mrs as mrs_lib, ordering as ordering_lib
-from repro.core import parallel as parallel_lib, uda as uda_lib
+from repro.core import uda as uda_lib
 from repro.core.tracecount import counted_jit, fresh_counter
 from repro.dist import data_parallel as dp
 from repro.launch import mesh as mesh_lib
@@ -106,10 +105,7 @@ SHARD_MODES = {
 #   pallas_fused    — kernels/igd_fused per-tuple IGD (ref.py oracle:
 #                     bit-order-identical to the scan, fp32 tolerance);
 #                     lmf: the per-rating row kernel (rows.py)
-#   pallas_minibatch— one mean-gradient step per 256-row tile: a
-#                     DIFFERENT algorithm (hint-only; never auto-chosen)
-IMPLEMENTATIONS = ("xla_fold", "pallas_fused", "pallas_minibatch")
-PALLAS_IMPLEMENTATIONS = ("pallas_fused", "pallas_minibatch")
+IMPLEMENTATIONS = ("xla_fold", "pallas_fused")
 # the catalog kernel_loss that lowers through the row kernel
 # (kernels/igd_fused/rows.py) instead of the dense one
 ROW_KERNEL_LOSS = "lmf"
@@ -117,12 +113,6 @@ ROW_KERNEL_LOSS = "lmf"
 
 def canonical_ordering(name: str) -> str:
     return ORDERING_ALIASES.get(name, name)
-
-
-def plan_implementation(plan) -> str:
-    """The plan's lane-body lowering (duck-typed: pre-axis plan objects
-    read as xla_fold)."""
-    return getattr(plan, "implementation", "xla_fold")
 
 
 def kernel_eligibility(task, agg) -> Tuple[Optional[str], str]:
@@ -178,12 +168,6 @@ def require_kernel_loss(task, agg, implementation: str) -> str:
         raise ValueError(
             f"implementation={implementation!r} needs a kernel-eligible "
             f"aggregate: {why}"
-        )
-    if loss == ROW_KERNEL_LOSS and implementation != "pallas_fused":
-        raise ValueError(
-            f"implementation={implementation!r} needs a kernel-eligible "
-            "aggregate: the row kernel (lmf) has only the sequential "
-            "pallas_fused lowering"
         )
     return loss
 
@@ -306,7 +290,7 @@ def build_epoch_fn(task, agg, plan) -> Callable:
     """The chosen scheme's raw (unjitted) epoch function
     ``(state_or_carry, examples, rng) -> state_or_carry`` — the
     singleton lane body every other composition is built from."""
-    impl = plan_implementation(plan)
+    impl = plan.implementation
     if impl not in IMPLEMENTATIONS:
         raise ValueError(
             f"unknown implementation {impl!r}; valid: {IMPLEMENTATIONS}"
@@ -325,20 +309,6 @@ def build_epoch_fn(task, agg, plan) -> Callable:
         return lambda s, ex, rng: uda_lib.segmented_fold(
             agg, s, ex, plan.num_segments
         )
-    if plan.scheme == "shared_memory":
-        cfg = parallel_lib.SharedMemoryConfig(
-            scheme=plan.sm_scheme, workers=plan.sm_workers
-        )
-
-        def sm_epoch(state, ex, rng):
-            model = parallel_lib.hogwild_fold(
-                task, agg.step_size, state.model, ex, rng, cfg,
-                prox=agg.prox,
-            )
-            n = jax.tree.leaves(ex)[0].shape[0]
-            return uda_lib.IGDState(model, state.step + n, state.weight + n)
-
-        return sm_epoch
     if plan.scheme == "mrs":
         if plan.mrs_buffer <= 0:
             raise ValueError(
@@ -371,7 +341,7 @@ def build_chunk_epoch_fn(task, agg, plan, counter) -> Callable:
             f"fold; got scheme={plan.scheme!r}, ordering={plan.ordering!r} "
             "(the planner materializes for every other combination)"
         )
-    impl = plan_implementation(plan)
+    impl = plan.implementation
     if impl != "xla_fold":
         # the kernel folds each chunk with carried state: alphas continue
         # from state.step, so chunk boundaries stay invisible exactly as
@@ -407,12 +377,11 @@ def permuted_lane(agg, unroll: int):
 
 
 # ---------------------------------------------------------------------------
-# kernel lane bodies (the implementation axis's pallas_* lowerings)
+# kernel lane bodies (the implementation axis's pallas_fused lowering)
 # ---------------------------------------------------------------------------
 
 
-def kernel_lane_fold(agg, loss: str, *, minibatch: bool = False,
-                     interpret: Optional[bool] = None):
+def kernel_lane_fold(agg, loss: str, *, interpret: Optional[bool] = None):
     """The serial lane body lowered through a fused-IGD Pallas kernel:
     ``(state, ex) -> state`` over one epoch stream, advancing step/weight
     exactly like ``uda.fold`` (one per example). The per-example step
@@ -426,15 +395,15 @@ def kernel_lane_fold(agg, loss: str, *, minibatch: bool = False,
     TPU — ``igd_fused.ops.default_interpret``)."""
     from repro.kernels.igd_fused import ops as igd_ops
 
-    if loss == ROW_KERNEL_LOSS:  # one lowering (require_kernel_loss)
+    if loss == ROW_KERNEL_LOSS:
         return _row_lane(agg, interpret)
-    op = igd_ops.igd_fold_minibatch if minibatch else igd_ops.igd_fold
 
     def lane(state, ex):
         x, y = ex["x"], ex["y"]
         n = x.shape[0]
         alphas = agg.step_size(state.step + jnp.arange(n))
-        model = op(x, y, alphas, state.model, loss=loss, interpret=interpret)
+        model = igd_ops.igd_fold(x, y, alphas, state.model, loss=loss,
+                                 interpret=interpret)
         return uda_lib.IGDState(model, state.step + n, state.weight + n)
 
     return lane
@@ -465,15 +434,13 @@ def _row_lane(agg, interpret: Optional[bool]):
     return lane
 
 
-def kernel_permuted_lane(agg, loss: str, *, minibatch: bool = False,
-                         interpret: Optional[bool] = None):
+def kernel_permuted_lane(agg, loss: str, *, interpret: Optional[bool] = None):
     """The kernel lane behind a permutation: the kernel streams example
     tiles in array order, so the permutation is applied as one gather up
     front (same rows, same order, same floats as ``permuted_lane``'s
     in-scan gather — the kernel trades the per-step gather for a
     materialized permuted view, which is the layout it wants anyway)."""
-    lane = kernel_lane_fold(agg, loss, minibatch=minibatch,
-                            interpret=interpret)
+    lane = kernel_lane_fold(agg, loss, interpret=interpret)
 
     def permuted(state, data, perm):
         return lane(state, _take(data, perm))
@@ -483,11 +450,10 @@ def kernel_permuted_lane(agg, loss: str, *, minibatch: bool = False,
 
 def _kernel_lane_for(task, agg, implementation: str,
                      with_rng: bool = False):
-    """Build the lane body for a pallas_* implementation (validated)."""
+    """Build the lane body for the pallas_fused implementation
+    (validated)."""
     loss = require_kernel_loss(task, agg, implementation)
-    lane = kernel_lane_fold(
-        agg, loss, minibatch=implementation == "pallas_minibatch"
-    )
+    lane = kernel_lane_fold(agg, loss)
     if with_rng:
         return lambda s, ex, rng: lane(s, ex)
     return lane
@@ -578,7 +544,7 @@ def build_shard_block(
     bit-identical to the pre-mask fused path.
 
     ``implementation``/``kernel_loss`` select the lane body's lowering
-    (the implementation axis): ``pallas_*`` swaps the per-lane fold for
+    (the implementation axis): ``pallas_fused`` swaps the per-lane fold for
     the fused-IGD kernel — same alphas, same step/weight accounting, so
     the block's merge tree and compensated schedule are untouched.
     """
@@ -599,11 +565,10 @@ def build_shard_block(
                 f"implementation={implementation!r} shard blocks need the "
                 "kernel_loss resolved by the caller (require_kernel_loss)"
             )
-        mb = implementation == "pallas_minibatch"
         if mode == "segments":
-            lane = kernel_lane_fold(agg, kernel_loss, minibatch=mb)
+            lane = kernel_lane_fold(agg, kernel_loss)
         else:
-            lane = kernel_permuted_lane(agg, kernel_loss, minibatch=mb)
+            lane = kernel_permuted_lane(agg, kernel_loss)
     elif mode == "segments":
         lane = _lane_fold(agg, unroll)
     else:
@@ -809,7 +774,7 @@ class ShardedRunner:
         # the implementation axis rides into every block this runner
         # compiles; eligibility is resolved once (the compensated
         # aggregate keeps the task and prox, only the schedule changes)
-        self.implementation = plan_implementation(plan)
+        self.implementation = plan.implementation
         self.kernel_loss = (
             require_kernel_loss(task, self.agg_sharded, self.implementation)
             if self.implementation != "xla_fold" else None
@@ -909,11 +874,10 @@ def _build_fused(task, agg, prog: EpochProgram, n: int,
         # (one for each ordering shuffle, one per executor epoch)
         # replicate the singleton path exactly.
         mode = "fused"
-        impl = plan_implementation(plan)
+        impl = plan.implementation
         if impl != "xla_fold":
             lane_body = kernel_permuted_lane(
-                agg, require_kernel_loss(task, agg, impl),
-                minibatch=impl == "pallas_minibatch",
+                agg, require_kernel_loss(task, agg, impl)
             )
         else:
             lane_body = permuted_lane(agg, plan.unroll)
@@ -1046,7 +1010,7 @@ def _build_program(
 ) -> CompiledProgram:
     counter = counter if counter is not None else fresh_counter()
     plan = prog.plan
-    impl = plan_implementation(plan)
+    impl = plan.implementation
     if impl not in IMPLEMENTATIONS:
         raise ValueError(
             f"unknown implementation {impl!r}; valid: {IMPLEMENTATIONS}"
@@ -1066,7 +1030,7 @@ def _build_program(
                 program=prog, task=task, agg=agg, trace_counter=counter,
                 runner=ShardedRunner(task, agg, plan, counter),
             )
-        if getattr(plan, "source", "memory") == "table":
+        if plan.source == "table":
             epoch_fn = build_chunk_epoch_fn(task, agg, plan, counter)
         else:
             # Every non-MRS scheme's state is dead after the epoch call,

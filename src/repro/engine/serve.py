@@ -83,7 +83,10 @@ from repro.engine.query import AnalyticsQuery
 # v3: Plan grew the implementation axis (fused-IGD kernel lanes) and
 # Calibration grew impl_per_row; old entries would silently re-plan the
 # kernel choice from stale constants, so they are invalidated.
-FORMAT_VERSION = 3
+# v4: the planner dropped the simulated shared-memory scheme (two Plan
+# fields) and the mean-gradient tile kernel (a rate in impl_per_row); a
+# v3 entry is re-planned, not loaded.
+FORMAT_VERSION = 4
 
 REJECT_QUEUE_FULL = "queue_full"
 REJECT_TASK_LIMIT = "task_limit"
@@ -628,12 +631,12 @@ class ServingEngine:
         _, task, agg = self.engine._aggregate_for(query)
         if (
             plan.parallelism != "sharded"
-            and program_lib.plan_implementation(plan) == "xla_fold"
+            and plan.implementation == "xla_fold"
         ):
             # the singleton plan's unroll was probed for a single fold;
             # the vmapped executable wants its own (measured, not
             # guessed — probes.probe_batch_unroll). Kernel lanes have no
-            # scan-unroll knob, so pallas_* plans skip the re-probe.
+            # scan-unroll knob, so pallas_fused plans skip the re-probe.
             plan = dataclasses.replace(
                 plan,
                 unroll=probes.probe_batch_unroll(

@@ -12,10 +12,7 @@ stream from the buffer pool. The TPU adaptation (DESIGN.md §5):
 * the strictly-sequential per-tuple dependence runs inside the kernel as a
   ``fori_loop`` over blocks of ``BLOCK`` rows, each statically unrolled
   row by row into VPU vector ops (D padded to 128): the block
-  recurrence, below;
-* a ``minibatch`` variant instead computes the whole tile's margins with
-  one MXU matvec and applies the summed update — trading IGD purity for
-  MXU utilization (both have exact jnp oracles in ref.py).
+  recurrence, below (its exact jnp oracle is ``ref.igd_fold_ref``).
 
 Losses: "lr" (logistic), "svm" (hinge), "lsq" (least squares).
 
@@ -55,12 +52,12 @@ again from its starting model, one row at a time. The logistic and least-squares
 need no check. Rows with ``alpha = 0`` (the tail padding) get ``c = 0``
 exactly, so they leave both ``w`` and ``q`` untouched.
 
-The engine reaches these kernels through the EpochProgram
+The engine reaches this kernel through the EpochProgram
 ``implementation`` axis: ``engine/program.py`` lowers serial lane
-bodies onto ``ops.igd_fold`` / ``ops.igd_fold_minibatch`` for
-kernel-eligible tasks (``catalog.kernel_loss_for``), and the planner
-prices the choice from per-implementation micro-probes
-(``Calibration.impl_per_row``) — see ENGINE.md.
+bodies onto ``ops.igd_fold`` for kernel-eligible tasks
+(``catalog.kernel_loss_for``), and the planner prices the choice from a
+micro-probe of the kernel lane (``Calibration.impl_per_row``) — see
+ENGINE.md.
 """
 
 from __future__ import annotations
@@ -72,7 +69,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-TILE = 256  # examples per VMEM block (and per minibatch step)
+TILE = 256  # examples per VMEM block
 # rows per step of the serial kernel's block recurrence, four [8, 128]
 # sublane tiles: on a TPU v5e at Forest's shape (logreg), blocks of 16
 # rows took 8% longer per row and blocks of 64 only 4% less
@@ -153,53 +150,27 @@ def _igd_kernel(x_ref, y_ref, alpha_ref, w0_ref, wout_ref, wscr, *, loss: str,
         wout_ref[...] = wscr[...]
 
 
-def _minibatch_kernel(x_ref, y_ref, alpha_ref, w0_ref, wout_ref, wscr, *,
-                      loss: str, n_tiles: int):
-    t = pl.program_id(0)
-
-    @pl.when(t == 0)
-    def _init():
-        wscr[...] = w0_ref[...]
-
-    w = wscr[...]  # [1, D]
-    x = x_ref[...]  # [TILE, D]
-    # [1, D] x [TILE, D]^T -> [1, TILE]: one MXU pass for the tile's margins
-    wx = jax.lax.dot_general(
-        w, x, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )
-    y = y_ref[...]  # [1, TILE]
-    m = wx if loss == "lsq" else y * wx
-    c = _grad_scale(loss, m, y) * alpha_ref[...]
-    upd = jnp.dot(c, x, preferred_element_type=jnp.float32)  # [1, D]
-    wscr[...] = w - upd / x_ref.shape[0]
-
-    @pl.when(t == n_tiles - 1)
-    def _fin():
-        wout_ref[...] = wscr[...]
-
-
-def _fold_call(kernel, x, y, alpha, w0, *, name: str, loss: str,
-               y_alpha_space, interpret: bool):
-    """Shared pallas_call plumbing. x: [N, D] f32 (N % TILE == 0,
-    D % 128 == 0), y/alpha: [N], w0: [D] -> final w [D]. ``name`` is the
-    kernel's name in HLO, and so in a device trace, whether the call is
+def igd_fold(x, y, alpha, w0, *, loss: str = "lr", interpret: bool = False):
+    """Sequential IGD over all n examples. x: [N, D] f32 (N % TILE == 0,
+    D % 128 == 0), y/alpha: [N], w0: [D] -> final w [D]. The call is
+    named ``igd_serial`` in HLO, and so in a device trace, whether it is
     jitted alone or vmapped.
 
     Layouts the TPU compiler accepts: the model is a [1, D] block (and a
     [1, D] VMEM scratch carried across the sequential grid), and y/alpha
-    ride as [N / TILE, 1, TILE] so each grid step's block spans the
-    array's two minor dims, in ``y_alpha_space`` (SMEM for per-row
-    scalar reads, None for VMEM vectors)."""
+    ride as [N / TILE, 1, TILE] in SMEM, so each grid step's block spans
+    the array's two minor dims and each row reads its label and step as
+    scalars."""
     n, d = x.shape
     assert n % TILE == 0, f"N={n} not a multiple of {TILE}"
     assert d % 128 == 0, f"D={d} not a multiple of 128"
     n_tiles = n // TILE
     row_spec = pl.BlockSpec(
-        (None, 1, TILE), lambda t: (t, 0, 0), memory_space=y_alpha_space
+        (None, 1, TILE), lambda t: (t, 0, 0), memory_space=pltpu.SMEM
     )
     model_spec = pl.BlockSpec((1, d), lambda t: (0, 0))
     out = pl.pallas_call(
-        functools.partial(kernel, loss=loss, n_tiles=n_tiles),
+        functools.partial(_igd_kernel, loss=loss, n_tiles=n_tiles),
         grid=(n_tiles,),
         in_specs=[
             pl.BlockSpec((TILE, d), lambda t: (t, 0)),
@@ -211,7 +182,7 @@ def _fold_call(kernel, x, y, alpha, w0, *, name: str, loss: str,
         out_shape=jax.ShapeDtypeStruct((1, d), jnp.float32),
         scratch_shapes=[pltpu.VMEM((1, d), jnp.float32)],
         interpret=interpret,
-        name=name,
+        name="igd_serial",
     )(
         x,
         y.reshape(n_tiles, 1, TILE),
@@ -219,21 +190,3 @@ def _fold_call(kernel, x, y, alpha, w0, *, name: str, loss: str,
         w0.reshape(1, d),
     )
     return out[0]
-
-
-def igd_fold(x, y, alpha, w0, *, loss: str = "lr", interpret: bool = False):
-    """Sequential IGD over all n examples; y and alpha are read as
-    scalars from SMEM, one per row."""
-    return _fold_call(_igd_kernel, x, y, alpha, w0, name="igd_serial",
-                      loss=loss, y_alpha_space=pltpu.SMEM,
-                      interpret=interpret)
-
-
-def igd_fold_minibatch(x, y, alpha, w0, *, loss: str = "lr",
-                       interpret: bool = False):
-    """Minibatch variant: one gradient step per TILE (mean gradient),
-    margins computed with an MXU matmul; y and alpha are [1, TILE]
-    vectors in VMEM."""
-    return _fold_call(_minibatch_kernel, x, y, alpha, w0,
-                      name="igd_minibatch", loss=loss, y_alpha_space=None,
-                      interpret=interpret)

@@ -2,8 +2,8 @@
 
 These are the lane bodies behind the EpochProgram compiler's
 ``implementation`` axis (``repro.engine.program.build_program`` lowers
-serial lane bodies of kernel-eligible plans through ``igd_fold`` /
-``igd_fold_minibatch``; the planner prices them against the XLA fold
+serial lane bodies of kernel-eligible plans through ``igd_fold``, or
+``lmf_fold`` for ratings; the planner prices them against the XLA fold
 from micro-probes — see ``repro.engine.probes``). ``interpret``
 defaults to the backend (``default_interpret``): interpret mode on the
 CPU, compiled on a TPU, so no caller runs the interpreter on the chip
@@ -69,26 +69,6 @@ def igd_fold(x, y, alpha, w0, *, loss="lr", interpret=None, use_kernel=True):
     xp, yp, ap, wp, d = _pad(x, y, alpha, w0)
     out = K.igd_fold(xp, yp, ap, wp, loss=loss,
                      interpret=_resolve(interpret))
-    return out[:d]
-
-
-@functools.partial(jax.jit, static_argnames=("loss", "interpret", "use_kernel"))
-def igd_fold_minibatch(x, y, alpha, w0, *, loss="lr", interpret=None,
-                       use_kernel=True):
-    """One mean-gradient step per TILE rows (margins via one MXU matvec).
-
-    Ragged tails are defined BY the padding: the last tile's mean is
-    taken over the full TILE with the pad contributing zero gradient, so
-    the escape hatch must see the same padded stream as the kernel —
-    the unpadded ref would reshape-fail on N % TILE != 0 and, worse,
-    divide the tail by a different count."""
-    if not use_kernel:
-        xp, yp, ap, wp, d = _pad(x, y, alpha, w0)
-        out = R.igd_fold_minibatch_ref(xp, yp, ap, wp, loss=loss, tile=K.TILE)
-        return out[:d]
-    xp, yp, ap, wp, d = _pad(x, y, alpha, w0)
-    out = K.igd_fold_minibatch(xp, yp, ap, wp, loss=loss,
-                               interpret=_resolve(interpret))
     return out[:d]
 
 

@@ -30,24 +30,6 @@ def igd_fold_ref(x, y, alpha, w0, *, loss: str = "lr"):
     return w
 
 
-def igd_fold_minibatch_ref(x, y, alpha, w0, *, loss: str = "lr", tile: int = 256):
-    """One mean-gradient step per tile."""
-    n, d = x.shape
-    xt = x.reshape(n // tile, tile, d)
-    yt = y.reshape(n // tile, tile)
-    at = alpha.reshape(n // tile, tile)
-
-    def body(w, ex):
-        xb, yb, ab = ex
-        wx = xb @ w
-        m = wx if loss == "lsq" else yb * wx
-        c = _grad_scale(loss, m, yb) * ab
-        return w - (c @ xb) / tile, None
-
-    w, _ = jax.lax.scan(body, w0, (xt, yt, at))
-    return w
-
-
 def lmf_fold_ref(i, j, v, alpha, left, right, *, c_row: float, c_col: float):
     """Sequential row-sparse lmf IGD via lax.scan: rating k steps row
     i[k] of ``left`` and row j[k] of ``right`` by ``jax.grad`` of the

@@ -32,13 +32,31 @@ from repro.launch import hlo_analysis as hlo
 from repro.launch import mesh as mesh_lib
 from repro.launch.inputs import input_specs
 from repro.launch.mesh import make_production_mesh
-from repro.launch.serve import make_decode_step, make_prefill_step
 from repro.launch.train import make_train_step
 from repro.models import lm
 from repro.optim import IGD, AdamW
 
 # devices needed by the largest mesh this module builds (2 x 16 x 16)
 DRYRUN_DEVICES = 512
+
+
+def make_prefill_step(cfg):
+    def prefill_step(params, batch):
+        return lm.prefill(
+            params, batch["tokens"], cfg, prefix_embeds=batch.get("prefix_embeds")
+        )
+
+    return prefill_step
+
+
+def make_decode_step(cfg):
+    def decode_step(params, batch):
+        logits, cache = lm.decode_step(params, batch["tokens"], batch["cache"], cfg)
+        # greedy next token (sampling lives host-side in the server loop)
+        next_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        return next_tok, cache
+
+    return decode_step
 
 
 def build_cell(cfg, shape, mesh, *, grad_accum=8, optimizer="sgd",

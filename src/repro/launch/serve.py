@@ -1,16 +1,9 @@
-"""Serving entry points.
-
-Two serving surfaces share this module:
-
-* **LM serving**: batched prefill and KV-cache decode step builders
-  (``make_prefill_step`` / ``make_decode_step``), used by
-  ``examples/serve_lm.py``.
-* **Analytics serving**: the engine's high-QPS front-end
-  (``repro.engine.serve``) — ``make_analytics_server`` builds a
-  ``ServingEngine`` (admission control + cross-query batching + optional
-  persistent plan cache) and ``serve_analytics`` runs a submit-and-drain
-  load, returning the tickets. ``benchmarks/serve_bench.py`` drives its
-  offered-load sweeps through these.
+"""Analytics serving entry points: the engine's high-QPS front-end
+(``repro.engine.serve``). ``make_analytics_server`` builds a
+``ServingEngine`` (admission control + cross-query batching + optional
+persistent plan cache) and ``serve_analytics`` runs a submit-and-drain
+load, returning the tickets. ``benchmarks/serve_bench.py`` drives its
+offered-load sweeps through these.
 """
 
 from __future__ import annotations
@@ -18,12 +11,8 @@ from __future__ import annotations
 import os
 from typing import Iterable, List, Optional
 
-import jax
-import jax.numpy as jnp
-
 from repro import obs
 from repro.engine import serve as serve_lib
-from repro.models import lm
 
 
 def make_analytics_server(
@@ -85,21 +74,3 @@ def serve_analytics(
     rec.export_chrome_trace(os.path.join(trace_dir, "serve.trace.json"))
     return tickets
 
-
-def make_prefill_step(cfg):
-    def prefill_step(params, batch):
-        return lm.prefill(
-            params, batch["tokens"], cfg, prefix_embeds=batch.get("prefix_embeds")
-        )
-
-    return prefill_step
-
-
-def make_decode_step(cfg):
-    def decode_step(params, batch):
-        logits, cache = lm.decode_step(params, batch["tokens"], batch["cache"], cfg)
-        # greedy next token (sampling lives host-side in the server loop)
-        next_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        return next_tok, cache
-
-    return decode_step

@@ -4,6 +4,9 @@ cache behavior, and the ≤30-LoC new-technique guarantee."""
 import dataclasses
 import inspect
 import math
+import os
+import subprocess
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -17,6 +20,32 @@ from repro.engine import catalog
 from repro.tasks import Task
 
 RNG = jax.random.PRNGKey(0)
+
+
+# ---------------------------------------------------------------------------
+# import layering
+# ---------------------------------------------------------------------------
+
+
+def test_the_analytics_imports_load_no_lm_module():
+    """The engine and its serving entry point stand apart from the LM
+    stack: importing them loads no model, architecture config, attention
+    or decode kernel, and no LM collective."""
+    script = (
+        "import sys\n"
+        "import repro.engine, repro.launch.serve\n"
+        "stack = ('repro.models', 'repro.configs', "
+        "'repro.kernels.attention', 'repro.kernels.decode', "
+        "'repro.dist.collectives')\n"
+        "print(sorted(m for m in sys.modules if m.startswith(stack)))\n"
+    )
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=os.path.join(os.path.dirname(__file__), "..",
+                                       "src"))
+    out = subprocess.run([sys.executable, "-c", script], env=env,
+                         capture_output=True, text=True, timeout=300)
+    assert out.returncode == 0, out.stderr[-3000:]
+    assert out.stdout.strip().splitlines()[-1] == "[]", out.stdout
 
 
 # ---------------------------------------------------------------------------
@@ -222,7 +251,6 @@ def test_forced_plans_execute_all_schemes():
     plans = [
         engine.Plan("shuffle_once", "serial"),
         engine.Plan("shuffle_once", "segmented", num_segments=4),
-        engine.Plan("shuffle_once", "shared_memory", sm_scheme="nolock"),
         engine.Plan("clustered", "mrs", mrs_buffer=32),
     ]
     for p in plans:

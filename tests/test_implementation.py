@@ -1,5 +1,4 @@
-"""The EpochProgram implementation axis (xla_fold | pallas_fused |
-pallas_minibatch).
+"""The EpochProgram implementation axis (xla_fold | pallas_fused).
 
 Pins the contract from both directions: ``implementation=xla_fold`` is
 bit-identical to the default lane bodies (the axis is a pure addition),
@@ -11,7 +10,6 @@ contradictory hints fail loudly instead of silently falling back.
 """
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -145,27 +143,6 @@ def test_serve_fused_batch_pallas_matches_singleton():
         )
 
 
-def test_pallas_minibatch_is_a_different_algorithm_that_converges():
-    """pallas_minibatch takes one mean-gradient step per TILE — it is
-    hint-only and NOT expected to match the sequential fold, but it must
-    run end-to-end and still make progress on the loss."""
-    data = _data(512)
-    eng = engine.Engine()
-    res = eng.run(
-        _q(data, epochs=5,
-           hints={"implementation": "pallas_minibatch"})
-    )
-    assert res.plan.implementation == "pallas_minibatch"
-    assert np.all(np.isfinite(np.asarray(res.model)))
-    from repro.engine import catalog
-    loss0 = float(
-        catalog.get("logreg").make_task(dim=4).full_loss(
-            jnp.zeros(4), data
-        )
-    )
-    assert res.losses[-1] < 0.5 * loss0
-
-
 # ---------------------------------------------------------------------------
 # planner: probe-priced choice, EXPLAIN surfacing
 # ---------------------------------------------------------------------------
@@ -178,8 +155,7 @@ def test_planner_prices_implementations_from_probes():
     line shows the measured us/epoch for every implementation."""
     rep = engine.explain(_q(_data()))
     rates = rep.calibration.impl_per_row
-    assert rates.get("pallas_fused", 0.0) > 0.0
-    assert rates.get("pallas_minibatch", 0.0) > 0.0
+    assert set(rates) == {"pallas_fused"} and rates["pallas_fused"] > 0.0
     assert any(
         c.plan.implementation == "pallas_fused" for c in rep.candidates
     )
@@ -245,5 +221,13 @@ def test_forced_kernel_conflicts_with_nonserial_scheme():
 
 
 def test_unknown_implementation_hint_raises():
+    """The axes name two lowerings and three singleton schemes; any other
+    name is refused, the removed minibatch kernel and shared-memory
+    simulator among them."""
     with pytest.raises(ValueError):
         engine.explain(_q(_data(), hints={"implementation": "cuda"}))
+    with pytest.raises(ValueError, match="unknown implementation hint"):
+        engine.explain(_q(_data(),
+                          hints={"implementation": "pallas_minibatch"}))
+    with pytest.raises(ValueError, match="unknown scheme hint"):
+        engine.explain(_q(_data(), hints={"scheme": "shared_memory"}))

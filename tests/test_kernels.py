@@ -34,20 +34,6 @@ def test_igd_fold_matches_ref(loss, n, d):
                                rtol=2e-4, atol=2e-5)
 
 
-@pytest.mark.parametrize("loss", ["lr", "svm", "lsq"])
-def test_igd_minibatch_matches_ref(loss):
-    n, d = 512, 160
-    x = jax.random.normal(RNG, (n, d), jnp.float32) / jnp.sqrt(d)
-    y = jnp.sign(jax.random.normal(jax.random.fold_in(RNG, 1), (n,)))
-    alpha = 0.2 * jnp.ones((n,))
-    w0 = jnp.zeros((d,))
-    wk = igd_ops.igd_fold_minibatch(x, y, alpha, w0, loss=loss)
-    wr = igd_ref.igd_fold_minibatch_ref(x, y, alpha, w0, loss=loss,
-                                        tile=igd_kernel.TILE)
-    np.testing.assert_allclose(np.asarray(wk), np.asarray(wr),
-                               rtol=2e-4, atol=2e-5)
-
-
 def _igd_inputs(n, d, seed=3):
     rng = jax.random.PRNGKey(seed)
     x = jax.random.normal(rng, (n, d), jnp.float32) / jnp.sqrt(d)
@@ -104,30 +90,11 @@ def test_igd_pad_rows_are_bitwise_noops(loss, n, d):
     )
 
 
-@pytest.mark.parametrize("loss", ["lr", "svm", "lsq"])
-@pytest.mark.parametrize("n,d", _PAD_SHAPES)
-def test_igd_minibatch_padding_matrix(loss, n, d):
-    """Minibatch parity on ragged shapes. The tail tile's mean is taken
-    over the full TILE with zero-gradient pad rows (the padding DEFINES
-    the ragged semantics), so the oracle is the jnp minibatch ref over
-    the same padded stream — which is exactly what use_kernel=False
-    runs."""
-    x, y, alpha, w0 = _igd_inputs(n, d)
-    wk = igd_ops.igd_fold_minibatch(x, y, alpha, w0, loss=loss)
-    xp, yp, ap, wp, _ = igd_ops._pad(x, y, alpha, w0)
-    wr = igd_ref.igd_fold_minibatch_ref(xp, yp, ap, wp, loss=loss,
-                                        tile=igd_kernel.TILE)[:d]
-    assert wk.shape == (d,)
-    np.testing.assert_allclose(np.asarray(wk), np.asarray(wr),
-                               rtol=2e-4, atol=2e-5)
-
-
-@pytest.mark.parametrize("op", [igd_ops.igd_fold, igd_ops.igd_fold_minibatch])
+@pytest.mark.parametrize("op", [igd_ops.igd_fold])
 def test_igd_escape_hatch_matches_kernel(op):
     """use_kernel=False is the oracle path: it must accept the same
-    ragged shapes the kernel accepts (the minibatch hatch used to crash
-    on N % TILE != 0 by handing unpadded rows to the reshape-based ref)
-    and agree with the kernel within fold tolerance."""
+    ragged shapes the kernel accepts and agree with the kernel within
+    fold tolerance."""
     x, y, alpha, w0 = _igd_inputs(300, 72)
     wk = op(x, y, alpha, w0, loss="lsq", use_kernel=True)
     wh = op(x, y, alpha, w0, loss="lsq", use_kernel=False)

@@ -237,6 +237,36 @@ def test_row_kernel_matches_its_oracle():
         assert np.max(np.abs(x - y)) / np.max(np.abs(y)) <= 1e-5
 
 
+@pytest.mark.parametrize("rank,n_rows,n_cols", [
+    (1, 7, 5), (128, 33, 17), (129, 40, 24),
+])
+@pytest.mark.parametrize("n", [1, 511, 513, 1027])
+def test_row_kernel_padding_matrix(n, rank, n_rows, n_cols):
+    """The padding matrix of the row kernel against its oracle: a single
+    rating, one short of and one past the kernel's tile (and two tiles
+    and three ratings), a rank of one lane, of exactly one lane multiple
+    and one past it, and tables whose row counts are not multiples of 8.
+    Within 1e-5 relative per table (the dot sums the padded lanes in
+    another order)."""
+    from repro.kernels.igd_fused import ops, ref, rows
+
+    assert (511, 513, 1027) == (rows.TILE - 1, rows.TILE + 1,
+                                2 * rows.TILE + 3)
+    data = _ratings_at(n, n_rows, n_cols, seed=11)
+    keys = jax.random.split(jax.random.PRNGKey(n), 2)
+    left = 0.3 * jax.random.normal(keys[0], (n_rows, rank), jnp.float32)
+    right = 0.3 * jax.random.normal(keys[1], (n_cols, rank), jnp.float32)
+    alpha = 0.02 / (1.0 + jnp.arange(n, dtype=jnp.float32) / n)
+    args = (data["i"], data["j"], data["v"], alpha, left, right)
+    scales = {"c_row": 1e-3, "c_col": 3e-3}
+    for x, y, t in zip(ops.lmf_fold(*args, **scales),
+                       ref.lmf_fold_ref(*args, **scales), (left, right)):
+        x, y = np.asarray(x), np.asarray(y)
+        assert x.shape == y.shape == t.shape
+        assert np.all(np.isfinite(x))
+        assert np.max(np.abs(x - y)) / np.max(np.abs(y)) <= 1e-5
+
+
 def test_row_kernel_zero_alpha_ratings_are_bitwise_noops():
     """The padding the wrapper adds carries alpha = 0: such ratings write
     back the rows they read, bit for bit, and the padded rank lanes stay
@@ -295,8 +325,10 @@ def test_kernel_eligibility_of_the_row_kernel():
     assert loss is None and "VMEM" in why
     with pytest.raises(ValueError, match="kernel-eligible"):
         program.require_kernel_loss(big.task, big, "pallas_fused")
-    with pytest.raises(ValueError, match="pallas_fused lowering"):
-        program.require_kernel_loss(agg.task, agg, "pallas_minibatch")
+    # pallas_fused is the one kernel lowering: any other name is refused
+    with pytest.raises(ValueError, match="unknown implementation"):
+        program.build_epoch_fn(agg.task, agg, engine.Plan(
+            "clustered", "serial", implementation="pallas_minibatch"))
 
 
 def _seeded_plan(query, **rates):
@@ -350,7 +382,7 @@ def test_the_row_kernel_probe_times_one_lane():
 
 
 def test_a_forest_shaped_query_keeps_its_probes_and_plan():
-    """Dense ``{x, y}`` rows still probe both dense kernel lanes, and at
+    """Dense ``{x, y}`` rows still probe the dense kernel lane, and at
     rates like the Forest cell's (clustered by label) the planner still
     picks the shuffle_once kernel plan."""
     from repro.engine import probes
@@ -363,10 +395,9 @@ def test_a_forest_shaped_query_keeps_its_probes_and_plan():
                                   tolerance=0.0, seed=1)
     _, _, agg = engine.Engine()._aggregate_for(query)
     rates = probes._probe_implementations(agg, data, agg.initialize(RNG), 512)
-    assert set(rates) == {"pallas_fused", "pallas_minibatch"}
+    assert set(rates) == {"pallas_fused"}
     rep = _seeded_plan(query, shuffle=4.6e-8, fold=1e-6,
-                       impl={"pallas_fused": 3.3e-8,
-                             "pallas_minibatch": 1e-8})
+                       impl={"pallas_fused": 3.3e-8})
     chosen = rep.chosen
     assert (chosen.ordering, chosen.scheme, chosen.parallelism,
             chosen.implementation) == (

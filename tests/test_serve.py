@@ -399,6 +399,40 @@ def test_plan_key_names_the_platform(tmp_path, monkeypatch):
     assert e2.stats["plans_computed"] == 1
 
 
+def test_a_format_3_entry_is_replanned_and_rewritten(tmp_path):
+    """Format 3 stored plans with the shared-memory scheme's fields and
+    the minibatch kernel's probed rate. Such an entry reads as a miss:
+    the next run re-plans the query and rewrites the entry at format 4."""
+    import json
+
+    q = _q(synthetic.dense_classification(RNG, 128, 4))
+    e1 = engine.Engine(plan_store=serve.PlanStore(str(tmp_path)))
+    e1.explain(q)
+    path = e1.plan_store._path(e1._query_plan_key(q))
+    with open(path) as f:
+        entry = json.load(f)
+    assert serve.FORMAT_VERSION == 4 and entry["version"] == 4
+    report = entry["report"]
+    for p in [report["chosen"]] + [c["plan"] for c in report["candidates"]]:
+        p.update(sm_scheme="nolock", sm_workers=8)
+    report["calibration"]["impl_per_row"]["pallas_minibatch"] = 1e-8
+    with open(path, "w") as f:
+        json.dump(dict(entry, version=3), f)
+
+    probes.clear_cache()
+    e2 = engine.Engine(plan_store=serve.PlanStore(str(tmp_path)))
+    rep = e2.explain(q)
+    assert e2.stats["plan_disk_hits"] == 0
+    assert e2.stats["plans_computed"] == 1
+    with open(path) as f:
+        entry = json.load(f)
+    assert entry["version"] == 4
+    assert "sm_scheme" not in entry["report"]["chosen"]
+    assert "pallas_minibatch" not in (
+        entry["report"]["calibration"]["impl_per_row"])
+    assert e2.plan_store.load(e2._query_plan_key(q), q).chosen == rep.chosen
+
+
 def test_fingerprint_catches_interior_reorder():
     """A same-multiset, interior-only reordering (label-clustered vs
     shuffled — exactly the statistic the planner keys on) must change

@@ -84,12 +84,6 @@ def test_igd_fold_compiles_for_v5e(one_chip, loss):
     _assert_kernel_fits(fold.lower(*_table_specs(one_chip)).compile())
 
 
-def test_igd_fold_minibatch_compiles_for_v5e(one_chip):
-    fold = jax.jit(lambda x, y, a, w: igd_ops.igd_fold_minibatch(
-        x, y, a, w, loss="lr", interpret=False))
-    _assert_kernel_fits(fold.lower(*_table_specs(one_chip)).compile())
-
-
 @pytest.mark.parametrize("ordering", ["clustered", "shuffle_always"])
 def test_fused_kernel_lane_compiles_for_v5e(one_chip, ordering):
     """The fused serving batch's lane body (``program._build_fused``):
@@ -123,29 +117,6 @@ def test_fused_kernel_lane_compiles_for_v5e(one_chip, ordering):
     _assert_kernel_fits(jax.jit(lane).lower(*args).compile())
 
 
-@pytest.mark.parametrize("kernel,lanes", [
-    ("igd_serial", 0), ("igd_serial", 2), ("igd_minibatch", 0),
-])
-def test_the_kernel_is_named_in_the_compiled_program(one_chip, kernel,
-                                                     lanes):
-    """A profiler names a device operation after its HLO instruction, so
-    the pallas_call's ``name=`` is what a trace shows: the same stem for
-    the kernel alone and vmapped over query lanes."""
-    fold = {"igd_serial": igd_ops.igd_fold,
-            "igd_minibatch": igd_ops.igd_fold_minibatch}[kernel]
-
-    def lane(x, y, a, w):
-        return fold(x, y, a, w, loss="lr", interpret=False)
-
-    n = 4096
-    x, y, a, w = _table_specs(one_chip, n=n)
-    if lanes:
-        lane = jax.vmap(lane, in_axes=(None, None, None, 0))
-        w = _spec(one_chip, (lanes, FOREST_DIM))
-    text = jax.jit(lane).lower(x, y, a, w).compile().as_text()
-    assert f"%{kernel}." in text
-
-
 # MovieLens-1M at the benchmark's rank: the row kernel's tables
 ML_USERS, ML_MOVIES, ML_RATINGS, ML_RANK = 6040, 3706, 1_000_209, 50
 
@@ -158,6 +129,38 @@ def _row_fold(i, j, v, a, left, right):
 def _rating_specs(sharding, n):
     return (_spec(sharding, (n,), jnp.int32), _spec(sharding, (n,), jnp.int32),
             _spec(sharding, (n,)), _spec(sharding, (n,)))
+
+
+# the dense fold's cases go by its kernel's name, the lmf row fold's by
+# its technique: both kernels are named igd_serial
+@pytest.mark.parametrize("fold,lanes", [
+    ("igd_serial", 0), ("igd_serial", 2), ("lmf", 0), ("lmf", 2),
+])
+def test_the_kernel_is_named_in_the_compiled_program(one_chip, fold, lanes):
+    """A profiler names a device operation after its HLO instruction, so
+    the pallas_call's ``name=`` is what a trace shows: the same stem for
+    the kernel alone and vmapped over query lanes. The lmf row kernel
+    carries the dense kernel's name, ``igd_serial``, which is how
+    ``kernel_roofline.lmf`` finds it in a trace."""
+    if fold == "lmf":
+        lane = _row_fold
+        stack = (lanes,) if lanes else ()
+        if lanes:
+            lane = jax.vmap(lane, in_axes=(None,) * 4 + (0, 0))
+        args = (*_rating_specs(one_chip, 4096),
+                _spec(one_chip, stack + (40, ML_RANK)),
+                _spec(one_chip, stack + (24, ML_RANK)))
+    else:
+        def lane(x, y, a, w):
+            return igd_ops.igd_fold(x, y, a, w, loss="lr", interpret=False)
+
+        x, y, a, w = _table_specs(one_chip, n=4096)
+        if lanes:
+            lane = jax.vmap(lane, in_axes=(None, None, None, 0))
+            w = _spec(one_chip, (lanes, FOREST_DIM))
+        args = (x, y, a, w)
+    text = jax.jit(lane).lower(*args).compile().as_text()
+    assert "%igd_serial." in text
 
 
 @pytest.mark.parametrize("lanes", [0, 2])
