@@ -189,6 +189,9 @@ class Engine:
             loss_fn = _counted_jit(
                 lambda model, data: task.full_loss(model, data), loss_counter
             )
+            share = program_lib.lmf_block_share(task, agg, plan, query.data)
+            if share is not None:
+                obs.metrics.set_gauge("kernel.lmf_block_share", share)
             obs.metrics.observe("engine.compile_s", time.perf_counter() - t0)
         compiled = CompiledPlan(
             key=key, plan=plan, agg=agg, task=task,

@@ -434,6 +434,25 @@ def _row_lane(agg, interpret: Optional[bool]):
     return lane
 
 
+def lmf_block_share(task, agg, plan, data) -> Optional[float]:
+    """The share of an epoch's ratings that the row kernel folds from its
+    one-user blocks (``rows.block_share``) under ``plan``, where its
+    stream is the table's stored order: a clustered serial singleton
+    ``pallas_fused`` plan of ``lmf`` over an in-memory table. None for
+    any other plan, whose stream is not known before its fit. One small
+    device reduction and one read, so take it when the plan is compiled,
+    never inside a fit."""
+    from repro.kernels.igd_fused import rows as rows_kernel
+
+    if (plan.implementation != "pallas_fused"
+            or kernel_eligibility(task, agg)[0] != ROW_KERNEL_LOSS
+            or (plan.ordering, plan.scheme, plan.parallelism, plan.source)
+            != ("clustered", "serial", "singleton", "memory")
+            or not isinstance(data, dict)):
+        return None
+    return float(rows_kernel.block_share(data["i"], data["j"]))
+
+
 def kernel_permuted_lane(agg, loss: str, *, interpret: Optional[bool] = None):
     """The kernel lane behind a permutation: the kernel streams example
     tiles in array order, so the permutation is applied as one gather up

@@ -442,3 +442,201 @@ def test_sharded_lanes_run_the_row_kernel():
     assert res.plan.parallelism == "sharded"
     assert res.plan.implementation == "pallas_fused"
     _close(res.model, ref.model, tol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# the row kernel's block path: a block of BLOCK ratings of one user and
+# distinct movies takes its errors from one batched dot and an in-block
+# Gram of its movie rows; any other block is folded one rating at a time
+# ---------------------------------------------------------------------------
+
+
+def _runs(lengths, n_cols, seed=13):
+    """A user-sorted stream: run u holds lengths[u] ratings of user u,
+    each of a distinct movie."""
+    rng = np.random.default_rng(seed)
+    i = np.repeat(np.arange(len(lengths), dtype=np.int32), lengths)
+    j = np.concatenate([rng.choice(n_cols, k, replace=False)
+                        for k in lengths]).astype(np.int32)
+    v = rng.integers(1, 6, i.size).astype(np.float32)
+    return i, j, v
+
+
+def _fold_against_oracle(i, j, v, n_rows, n_cols, rank=50, alpha=None):
+    from repro.kernels.igd_fused import ops, ref
+
+    n = i.shape[0]
+    keys = jax.random.split(jax.random.PRNGKey(n), 2)
+    left = 0.3 * jax.random.normal(keys[0], (n_rows, rank), jnp.float32)
+    right = 0.3 * jax.random.normal(keys[1], (n_cols, rank), jnp.float32)
+    if alpha is None:
+        alpha = 0.02 / (1.0 + jnp.arange(n, dtype=jnp.float32) / n)
+    args = (jnp.asarray(i), jnp.asarray(j), jnp.asarray(v), alpha, left,
+            right)
+    scales = {"c_row": 1e-3, "c_col": 3e-3}
+    for x, y in zip(ops.lmf_fold(*args, **scales),
+                    ref.lmf_fold_ref(*args, **scales)):
+        x, y = np.asarray(x), np.asarray(y)
+        assert np.all(np.isfinite(x))
+        assert np.max(np.abs(x - y)) / np.max(np.abs(y)) <= 1e-5
+    return left, right
+
+
+def _block_marks(i, j):
+    """``rows.one_user_blocks`` of a stream padded to a whole tile, one
+    mark per block of its first ``len(i)`` ratings (each block's ratings
+    carry the same mark)."""
+    from repro.kernels.igd_fused import rows
+
+    pad = (-len(i)) % rows.TILE
+    marks = np.asarray(rows.one_user_blocks(
+        *(jnp.pad(jnp.asarray(c), (0, pad)) for c in (i, j))))
+    assert marks.dtype == np.int32 and marks.shape == (len(i) + pad,)
+    per_block = marks.reshape(-1, rows.BLOCK)
+    assert np.all(per_block == per_block[:, :1])
+    return per_block[:-(-len(i) // rows.BLOCK), 0]
+
+
+def test_one_user_blocks_reads_each_block_of_the_id_columns():
+    from repro.kernels.igd_fused import rows
+
+    b = rows.BLOCK
+    one = np.full(b, 3, np.int32)
+    distinct = np.arange(b, dtype=np.int32) + 5
+    blocks = [
+        (one, distinct, 1),  # one user, distinct movies
+        (np.r_[one[:-1], 4], distinct, 0),  # a second user in the block
+        (one, np.r_[distinct[:-1], distinct[0]], 0),  # a movie seen twice
+        (np.zeros(b, np.int32), np.zeros(b, np.int32), 0),  # the tail pad
+    ]
+    i = np.concatenate([blk[0] for blk in blocks])
+    j = np.concatenate([blk[1] for blk in blocks])
+    assert _block_marks(i, j).tolist() == [blk[2] for blk in blocks]
+
+
+def test_one_user_blocks_matches_a_loop_over_blocks():
+    """Short runs of users over few movies, across three tiles: the
+    elementwise marks equal a plain loop over the blocks."""
+    from repro.kernels.igd_fused import rows
+
+    rng = np.random.default_rng(5)
+    lengths = rng.integers(1, 40, 120)
+    i = np.repeat(np.arange(lengths.size, dtype=np.int32), lengths)
+    i = i[:3 * rows.TILE - 40]
+    j = rng.integers(0, 60, i.size).astype(np.int32)
+    want = [int(len(set(ib)) == 1 and len(set(jb)) == rows.BLOCK)
+            for ib, jb in zip(*(np.pad(c, (0, (-i.size) % rows.BLOCK))
+                                .reshape(-1, rows.BLOCK) for c in (i, j)))]
+    got = _block_marks(i, j).tolist()
+    assert got == want and 0 < sum(want) < len(want)
+
+
+@pytest.mark.parametrize("lengths,share", [
+    ((16, 8, 24), 1.0),  # runs of whole, aligned blocks
+    ((5, 11, 13, 3), 0.5),  # two blocks straddle a user boundary
+    ((8, 8, 5), 16 / 21),  # the last block is cut by the tail's padding
+])
+def test_the_block_share_of_a_stream_of_known_runs(lengths, share):
+    from repro.kernels.igd_fused import rows
+
+    assert rows.BLOCK == 8  # the runs above are laid out for blocks of 8
+    i, j, _ = _runs(lengths, 32)
+    got = float(rows.block_share(jnp.asarray(i), jnp.asarray(j)))
+    assert got == pytest.approx(share, abs=1e-7)
+
+
+def test_row_kernel_block_path_folds_a_user_sorted_stream():
+    """Runs of whole blocks of one user's distinct movies: every block
+    takes the block recurrence, and the fold agrees with the sequential
+    oracle within the file's kernel tolerance, across two tiles."""
+    from repro.kernels.igd_fused import rows
+
+    lengths = (40, 16, 64, 24, 8, 48, 32, 56, 16, 40, 72, 16, 8, 24, 80,
+               16, 48, 8, 40, 24)
+    i, j, v = _runs(lengths, 96)
+    assert i.size == 680 and rows.TILE < i.size
+    assert _block_marks(i, j).all()
+    _fold_against_oracle(i, j, v, len(lengths), 96)
+
+
+def test_row_kernel_blocks_that_straddle_users_fold_one_by_one():
+    """Runs of lengths that are not whole blocks: blocks within a run
+    take the block path, blocks across a boundary the per-rating one,
+    and the fold is the sequential oracle's either way."""
+    lengths = (5, 11, 13, 3, 21, 9, 30, 7, 19, 2, 17, 25, 6, 14, 33)
+    i, j, v = _runs(lengths, 48)
+    blocks = _block_marks(i, j)
+    assert 0 < blocks.sum() < blocks.size
+    _fold_against_oracle(i, j, v, len(lengths), 48)
+
+
+def test_row_kernel_a_movie_seen_twice_in_a_block_takes_the_fallback():
+    """One user's block that names a movie twice must read that movie's
+    row after its first update: the block is folded one rating at a
+    time, and agrees with the oracle."""
+    i, j, v = _runs((24, 16, 24), 40)
+    j[10] = j[13]  # block 1 (ratings 8-15) now sees one movie twice
+    assert _block_marks(i, j).tolist() == [1, 0, 1, 1, 1, 1, 1, 1]
+    _fold_against_oracle(i, j, v, 3, 40)
+
+
+def test_row_kernel_zero_alpha_blocks_are_bitwise_noops():
+    """alpha = 0 on ratings that take the block path, and on the padded
+    tail the wrapper adds, writes back every row bit for bit."""
+    from repro.kernels.igd_fused import ops
+
+    i, j, v = _runs((16, 24, 13), 40)
+    assert _block_marks(i[:40], j[:40]).all()
+    model = _lmf_agg(3, 40, 50, i.size).initialize(RNG).model
+    left, right = ops.lmf_fold(i, j, v, jnp.zeros((i.size,), jnp.float32),
+                               model["L"], model["R"], c_row=1e-3,
+                               c_col=1e-3)
+    assert np.array_equal(np.asarray(left), np.asarray(model["L"]))
+    assert np.array_equal(np.asarray(right), np.asarray(model["R"]))
+
+
+def test_row_kernel_block_path_under_two_vmapped_lanes():
+    """Two query lanes over one user-sorted stream, each from its own
+    tables: each lane agrees with the oracle run on its tables."""
+    from repro.kernels.igd_fused import ops, ref
+
+    i, j, v = _runs((16, 24, 13, 40, 8), 48)
+    n = i.size
+    alpha = 0.02 / (1.0 + jnp.arange(n, dtype=jnp.float32) / n)
+    keys = jax.random.split(jax.random.PRNGKey(2), 2)
+    left = 0.3 * jax.random.normal(keys[0], (2, 5, 50), jnp.float32)
+    right = 0.3 * jax.random.normal(keys[1], (2, 48, 50), jnp.float32)
+    cols = tuple(jnp.asarray(c) for c in (i, j, v)) + (alpha,)
+    scales = {"c_row": 1e-3, "c_col": 3e-3}
+    lanes = jax.vmap(lambda lt, rt: ops.lmf_fold(*cols, lt, rt, **scales))(
+        left, right)
+    for lane in range(2):
+        want = ref.lmf_fold_ref(*cols, left[lane], right[lane], **scales)
+        for x, y in zip(lanes, want):
+            x, y = np.asarray(x[lane]), np.asarray(y)
+            assert np.max(np.abs(x - y)) / np.max(np.abs(y)) <= 1e-5
+
+
+def test_the_compiled_plan_records_the_block_share():
+    """A clustered serial plan of lmf on the row kernel records, when it
+    is compiled, the share of its stored-order stream that the block path
+    folds (``kernel.lmf_block_share``); a shuffled stream's is not known
+    before its fit."""
+    from repro.engine import program
+    from repro.kernels.igd_fused import rows
+
+    i, j, v = _runs((5, 11, 13, 3, 21, 9, 24, 7), 24)
+    data = {"i": jnp.asarray(i), "j": jnp.asarray(j), "v": jnp.asarray(v)}
+    plan = engine.Plan("clustered", "serial", implementation="pallas_fused")
+    eng = engine.Engine()
+    eng.run(_query("lmf", data), plan=plan)
+    gauge = obs.metrics.snapshot("kernel.")["kernel.lmf_block_share"]
+    assert gauge["value"] == pytest.approx(
+        float(rows.block_share(data["i"], data["j"])))
+    assert 0.0 < gauge["value"] < 1.0
+    _, task, agg = eng._aggregate_for(_query("lmf", data))
+    shuffled = engine.Plan("shuffle_always", "serial",
+                           implementation="pallas_fused")
+    assert program.lmf_block_share(task, agg, shuffled, data) is None
+    assert program.lmf_block_share(
+        task, agg, engine.Plan("clustered", "serial"), data) is None

@@ -167,7 +167,10 @@ def test_the_kernel_is_named_in_the_compiled_program(one_chip, fold, lanes):
 def test_row_kernel_compiles_for_v5e(one_chip, lanes):
     """The lmf row kernel at the MovieLens-1M shape, both factor tables
     held in VMEM, alone and vmapped over query lanes (which run the
-    kernel lane by lane); named like the dense serial kernel."""
+    kernel lane by lane); named like the dense serial kernel. The kernel
+    holds both of its paths, the one-user block recurrence (its rows
+    gathered into one [BLOCK, 128] tile) and the rating-at-a-time
+    fallback, chosen per block from the id columns."""
     fold = _row_fold
     tables = ((ML_USERS, ML_RANK), (ML_MOVIES, ML_RANK))
     if lanes:
